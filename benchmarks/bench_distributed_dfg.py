@@ -1,61 +1,58 @@
-"""Distributed DFG scaling: shard_map map-reduce over 1..8 host devices.
+"""Distributed DFG scaling: shard_map map-reduce over 1, 2, 4, ... of the
+process's own devices (``jax.devices()``), in this process.
 
-Runs in a subprocess so the 8-device XLA flag never leaks into the parent
-(tests/benches must see 1 device)."""
+A chip belongs to one process, so no child is started: on a four-chip host
+this spans the four chips; on a CPU host it spans however many host
+devices JAX was started with (one, unless ``XLA_FLAGS`` asked for more).
+A wrong count raises.
+"""
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from .common import emit
 
-_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json, time
-import jax
-import numpy as np
-from repro.data import synthetic
-from repro.core import dfg
-from repro.distributed.dfg import dfg_sharded_host
 
-frame, tables = synthetic.generate(num_cases=200_000, num_activities=26, seed=5)
-n = frame.nrows
-# pad to multiple of 8 for even sharding
-pad = (-n) % 8
-if pad:
-    import jax.numpy as jnp
+def _shard_counts(n_dev: int):
+    s = 1
+    while s <= n_dev:
+        yield s
+        s *= 2
+
+
+def run(num_cases: int = 200_000):
+    from repro.core import dfg
     from repro.core.eventframe import EventFrame
-    cols = {k: jnp.pad(v, (0, pad), constant_values=-1) for k, v in frame.columns.items()}
-    rv = jnp.pad(frame.rows_valid(), (0, pad))
-    frame = EventFrame(cols, {}, rv)
+    from repro.data import synthetic
+    from repro.distributed.dfg import dfg_sharded_host
 
-ref = np.asarray(dfg(frame, 26, method="segment").counts)
-out = {}
-for shards in (1, 2, 4, 8):
-    f = lambda: jax.block_until_ready(dfg_sharded_host(frame, 26, shards))
-    f()
-    t0 = time.perf_counter(); f(); dt = time.perf_counter() - t0
-    got = np.asarray(dfg_sharded_host(frame, 26, shards).counts)
-    out[f"shards_{shards}"] = {"seconds": dt, "events_per_s": n / dt,
-                               "correct": bool((got == ref).all())}
-print(json.dumps(out))
-"""
-
-
-def run():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    res = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
-                         text=True, env=env, timeout=600)
-    if res.returncode != 0:
-        emit("distributed_dfg/error", 0.0, res.stderr.strip()[-200:])
-        return
-    data = json.loads(res.stdout.strip().splitlines()[-1])
-    base = data["shards_1"]["seconds"]
-    for k, v in data.items():
-        emit(f"distributed_dfg/{k}", v["seconds"],
-             f"events_per_s={v['events_per_s']:.0f};correct={v['correct']};"
-             f"speedup={base/v['seconds']:.2f}x")
+    frame, _ = synthetic.generate(num_cases=num_cases, num_activities=26,
+                                  seed=5)
+    n_dev = len(jax.devices())
+    n = frame.nrows
+    pad = (-n) % n_dev                  # even shards on every device count
+    if pad:
+        cols = {k: jnp.pad(v, (0, pad), constant_values=-1)
+                for k, v in frame.columns.items()}
+        frame = EventFrame(cols, {}, jnp.pad(frame.rows_valid(), (0, pad)))
+    ref = np.asarray(dfg(frame, 26, method="segment").counts)
+    base = None
+    for shards in _shard_counts(n_dev):
+        if frame.nrows % shards:
+            continue
+        got = np.asarray(jax.block_until_ready(
+            dfg_sharded_host(frame, 26, shards)).counts)
+        if not (got == ref).all():
+            raise AssertionError(f"sharded DFG over {shards} device(s) "
+                                 f"differs from the single-device DFG")
+        t0 = time.perf_counter()
+        jax.block_until_ready(dfg_sharded_host(frame, 26, shards))
+        dt = time.perf_counter() - t0
+        base = base or dt
+        emit(f"distributed_dfg/shards_{shards}", dt,
+             f"events_per_s={n / dt:.0f};correct=True;"
+             f"speedup={base / dt:.2f}x;device={jax.devices()[0].platform}")

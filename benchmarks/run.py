@@ -8,6 +8,8 @@ import argparse
 import sys
 import traceback
 
+from repro.core.backend import enable_compile_cache
+
 from . import (bench_complexity, bench_dataset, bench_discovery,
                bench_distributed_dfg, bench_fusion, bench_graph,
                bench_kernels, bench_query, bench_segment_ops, bench_serving,
@@ -74,7 +76,8 @@ SUITES = {
         dense=(512, 0.5) if full else (384, 0.5),
         num_cases=200_000 if full else 50_000,
         out_json="BENCH_graph.json"),
-    "distributed": lambda full: bench_distributed_dfg.run(),
+    "distributed": lambda full: bench_distributed_dfg.run(
+        num_cases=200_000 if full else 50_000),
     "streaming": lambda full: bench_streaming.run(
         num_cases=2_000_000 if full else 100_000),
 }
@@ -87,6 +90,7 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
     header()
     failed = []
     for name, fn in SUITES.items():

@@ -1,13 +1,17 @@
-"""Backend dispatch for the segmented-primitive layer (``kernels.segment_ops``).
+"""Backend dispatch for every Pallas kernel (``kernels.*``), and the
+persistent compile cache.
 
 Every core algorithm's inner loop is one of four named columnar primitives
 (``segment_reduce`` / ``histogram`` / ``pair_count`` / ``segmented_scan``),
-and each primitive has two interchangeable lowerings:
+and each primitive — like the graph semiring product and the DFG-count and
+attention kernels — has two interchangeable lowerings:
 
 * ``"pallas"`` — the Pallas TPU kernel (MXU one-hot matmul or VPU tiled
-  reduction over the sorted stream).  On non-TPU backends the kernel body
-  runs in interpret mode, so CPU-only CI validates the exact code the TPU
-  executes.
+  reduction over the sorted stream).  On a TPU it is compiled by Mosaic;
+  elsewhere the kernel body runs in interpret mode, which checks its
+  arithmetic against the reference but not that the TPU's compiler
+  accepts it — ``tests/test_tpu_compile.py`` compiles every kernel for a
+  described TPU v5e for that.
 * ``"xla"``    — the reference scatter/scan lowering (the paper's direct
   translation).  Row-order accumulation, used as the parity oracle and as
   the mandatory path for order-sensitive float accumulations.
@@ -30,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+from pathlib import Path
 
 import jax
 
@@ -62,16 +67,49 @@ def use_backend(name: str):
         set_backend(prev)
 
 
-def resolve(impl: str | None = None) -> str:
-    """Concrete lowering for a primitive call: ``"pallas"`` or ``"xla"``."""
+def resolve(impl: str | None = None, *, order_sensitive: bool = False,
+            assume_exact: bool = False) -> str:
+    """Concrete lowering for a primitive call: ``"pallas"`` or ``"xla"``.
+
+    One guardrail for a lowering that was *selected* (no ``impl``): an
+    order-sensitive accumulation — an inexact-float sum, whose rounding
+    depends on the order a tiling adds in — takes the row-order XLA
+    reference unless the caller asserts integer-valued operands with
+    ``assume_exact=True``.  That keeps every lowering and every chunking
+    bitwise equal.
+    """
     b = impl if impl is not None else _state["backend"]
     if b == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if b not in ("pallas", "xla"):
+        b = "pallas" if jax.default_backend() == "tpu" else "xla"
+    elif b not in ("pallas", "xla"):
         raise ValueError(f"unknown segment-ops impl {b!r}")
+    if impl is None and b == "pallas" and order_sensitive and not assume_exact:
+        return "xla"
     return b
 
 
 def interpret_mode() -> bool:
     """Pallas kernels run in interpret mode off-TPU (CPU CI validation)."""
     return jax.default_backend() != "tpu"
+
+
+CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    other path is set here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` — a fixed path, so a later run finds what an
+    earlier one compiled.  Every compiled program is cached, however fast
+    it compiled: a mining run compiles many small kernels, one per shape.
+    Call before the first compilation (entry points only, never on import).
+    """
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    path = os.environ.get(CACHE_ENV_VAR)
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

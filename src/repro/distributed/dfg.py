@@ -22,9 +22,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.dfg import DFG, dfg_kernel
 from repro.core.eventframe import ACTIVITY, CASE, EventFrame
 

@@ -17,9 +17,9 @@ from __future__ import annotations
 import functools
 
 import jax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.discovery import (AlphaModel, DiscoveryState, HeuristicsNet,
                                   discover_alpha, discover_heuristics,
                                   discovery_kernel)

@@ -31,9 +31,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import engine
 from repro.core.dfg import DFG, dfg_kernel
 from repro.core.discovery import DiscoveryState, discovery_kernel

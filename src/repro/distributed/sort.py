@@ -16,9 +16,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.core.eventframe import ACTIVITY, CASE, TIMESTAMP, EventFrame
 

@@ -32,10 +32,16 @@ import jax.numpy as jnp
 from repro.kernels.segment_ops import segment_reduce, segmented_affine
 
 
+def _varying(x, axis_name):
+    """A per-shard seed: scan carries must vary over the mesh axis as the
+    shard data they fold does (``shard_map``'s varying-axes check)."""
+    return jax.lax.pcast(x, axis_name, to="varying")
+
+
 def _base_fingerprints(m, b, starts, seg, ends, num_cases, *, axis_name,
                        n_dev):
-    ys0, _ = segmented_affine(m, b, starts, jnp.uint32(0))
-    ys1, _ = segmented_affine(m, b, starts, jnp.uint32(1))
+    ys0, _ = segmented_affine(m, b, starts, _varying(jnp.uint32(0), axis_name))
+    ys1, _ = segmented_affine(m, b, starts, _varying(jnp.uint32(1), axis_name))
     mr = ys1 - ys0              # shard-prefix map slope (0 after a restart)
     gather = jax.lax.all_gather(jnp.stack([mr[-1], ys0[-1]]), axis_name)
     idx = jax.lax.axis_index(axis_name)
@@ -43,7 +49,8 @@ def _base_fingerprints(m, b, starts, seg, ends, num_cases, *, axis_name,
     def fold(h, i):             # compose the preceding shards' maps, in order
         return jnp.where(i < idx, h * gather[i, 0] + gather[i, 1], h), None
 
-    h_in, _ = jax.lax.scan(fold, jnp.uint32(0), jnp.arange(n_dev))
+    h_in, _ = jax.lax.scan(fold, _varying(jnp.uint32(0), axis_name),
+                           jnp.arange(n_dev))
     hs = mr * h_in + ys0        # exact per-row hashes given the true carry
     fp = segment_reduce(jnp.where(ends, hs, jnp.uint32(0)), seg, num_cases,
                         "max")

@@ -7,9 +7,9 @@ instead of the n relaxation sweeps of Floyd–Warshall, which is what puts
 all-pairs graph queries on the MXU's terms:
 
 * :func:`bool_closure` — k-step boolean reachability.  The 0/1 operands
-  ride the ``plus_times`` MXU product and are re-thresholded after every
-  multiply, so values stay in {0, 1} and the closure is exact (hence
-  bitwise across lowerings) at any k.
+  ride the ``plus_times`` MXU product (``assume_exact``) and are
+  re-thresholded after every multiply, so values stay in {0, 1} and the
+  closure is exact (hence bitwise across lowerings) at any k.
 * :func:`minplus_closure` — all-pairs shortest distances over a weight
   matrix with ``+inf`` marking absent edges and a zero diagonal (the
   min-plus identity makes D ⊗ D the "paths of ≤ 2x the hops" relaxation).
@@ -41,17 +41,21 @@ def _backend():
 
 def semiring_matmul(a: jax.Array, b: jax.Array,
                     semiring: str = "plus_times", *,
-                    impl: str | None = None, **blocks) -> jax.Array:
+                    impl: str | None = None, assume_exact: bool = False,
+                    **blocks) -> jax.Array:
     """(M, N) float32 semiring product of ``a @ b`` (see module docstring).
 
     ``impl`` forces a lowering; otherwise ``core.backend.resolve()`` picks
     (Pallas on TPU, the XLA reference elsewhere — same contract as the
-    segment primitives).
+    segment primitives, guardrail included: a ``plus_times`` sum is
+    bitwise across lowerings only for integer-valued operands, so it takes
+    the XLA reference unless the caller passes ``assume_exact=True``).
     """
     if semiring not in SEMIRINGS:
         raise ValueError(f"unknown semiring {semiring!r}; one of {SEMIRINGS}")
     be = _backend()
-    chosen = be.resolve(impl)
+    chosen = be.resolve(impl, order_sensitive=semiring == "plus_times",
+                        assume_exact=assume_exact)
     if chosen == "pallas":
         return semiring_matmul_pallas(a, b, semiring,
                                       interpret=be.interpret_mode(), **blocks)
@@ -72,7 +76,7 @@ def _or_and(x: jax.Array, y: jax.Array, impl: str | None) -> jax.Array:
     # boolean AND-OR product as a thresholded 0/1 MXU matmul: path counts
     # are exact integers below 2^24, so ``> 0`` recovers the exact OR
     return semiring_matmul(x.astype(jnp.float32), y.astype(jnp.float32),
-                           "plus_times", impl=impl) > 0
+                           "plus_times", impl=impl, assume_exact=True) > 0
 
 
 def bool_closure(adj: jax.Array, k: int | None = None, *,

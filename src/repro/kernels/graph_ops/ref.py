@@ -1,6 +1,8 @@
 """XLA reference lowerings for the graph semiring products (parity oracles).
 
-``plus_times`` is a plain ``jnp.dot``; the tropical semirings are the
+``plus_times`` is a plain ``jnp.dot`` at ``HIGHEST`` precision (the TPU's
+default f32 matmul rounds operands to bfloat16, which is not exact for
+counts above 256); the tropical semirings are the
 row-blocked broadcast reduction — blocked so the (rows, K, N) candidate
 tensor never materializes for large graphs.  Tropical products are bitwise
 identical to the Pallas tiles for any block shape (min/max are
@@ -23,7 +25,8 @@ def semiring_matmul_ref(a: jax.Array, b: jax.Array,
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
     if semiring == "plus_times":
-        return jnp.dot(a, b, preferred_element_type=jnp.float32)
+        return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
     if semiring not in ("min_plus", "max_min"):
         raise ValueError(f"unknown semiring {semiring!r}")
     m = a.shape[0]

@@ -17,9 +17,12 @@ semiring       ``C[i, j]``                         graph meaning
 Tiling follows ``kernels.segment_ops.pair_count``: the output is cut into
 ``block_m x block_n`` tiles (grid axes i, j) and the contraction axis into
 ``block_k`` tiles (grid axis k — innermost, so each output block stays
-resident in VMEM across its accumulation).  ``plus_times`` rides the MXU
-(``jnp.dot``); the tropical semirings are VPU broadcast reductions over a
-narrow ``block_k`` (the (bm, bk, bn) candidate tensor bounds VMEM).
+resident in VMEM across its accumulation); every block is a whole number
+of (8, 128) vregs.  ``plus_times`` rides the MXU (``jnp.dot`` at
+``HIGHEST`` precision); the tropical semirings are VPU rank-1 updates, one
+per contraction index: column kk of the A tile broadcast along lanes
+against row kk of the B tile broadcast along sublanes, folded into the
+output tile with ``min``/``max`` — no (bm, bk, bn) candidate tensor.
 
 Exactness: ``min``/``max`` are order-insensitive and ``a + b`` /
 ``min(a, b)`` are single ops computed identically on every lowering, so
@@ -36,6 +39,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.tiles import LANES, SUBLANES, out_struct, round_up
 
 SEMIRINGS = ("plus_times", "min_plus", "max_min")
 
@@ -56,17 +61,17 @@ def _kernel(a_ref, b_ref, out_ref, *, semiring):
     a = a_ref[...]                              # (bm, bk)
     b = b_ref[...]                              # (bk, bn)
     if semiring == "plus_times":
-        out_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
-    elif semiring == "min_plus":
-        cand = jnp.min(a[:, :, None] + b[None, :, :], axis=1)
-        out_ref[...] = jnp.minimum(out_ref[...], cand)
-    else:                                       # max_min
-        cand = jnp.max(jnp.minimum(a[:, :, None], b[None, :, :]), axis=1)
-        out_ref[...] = jnp.maximum(out_ref[...], cand)
-
-
-def _round_up(x: int, m: int) -> int:
-    return max(m, ((x + m - 1) // m) * m)
+        out_ref[...] += jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+        return
+    acc = out_ref[...]
+    for kk in range(a.shape[1]):                # static unroll over block_k
+        col, row = a[:, kk:kk + 1], b[kk:kk + 1, :]
+        if semiring == "min_plus":
+            acc = jnp.minimum(acc, col + row)
+        else:                                   # max_min
+            acc = jnp.maximum(acc, jnp.minimum(col, row))
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("semiring", "block_m", "block_n",
@@ -74,25 +79,26 @@ def _round_up(x: int, m: int) -> int:
 def semiring_matmul_pallas(a: jax.Array, b: jax.Array,
                            semiring: str = "plus_times", *,
                            block_m: int = 128, block_n: int = 128,
-                           block_k: int | None = None,
+                           block_k: int = 128,
                            interpret: bool = True) -> jax.Array:
     """(M, N) float32 semiring product of ``a`` (M, K) and ``b`` (K, N).
 
     Inputs are padded with the semiring identity (pad rows/columns can
     never win a min/max and contribute 0 to a sum), the product runs on
     the padded tiles, and the (M, N) corner is sliced back out.
+    ``block_m`` is rounded up to whole 8-row sublane groups and
+    ``block_n``/``block_k`` to whole 128-lane vregs.
     """
     if semiring not in SEMIRINGS:
         raise ValueError(f"unknown semiring {semiring!r}; one of {SEMIRINGS}")
-    if block_k is None:
-        # MXU dot wants deep tiles; the (bm, bk, bn) broadcast wants thin
-        block_k = 128 if semiring == "plus_times" else 8
+    block_m = round_up(block_m, SUBLANES)
+    block_n, block_k = round_up(block_n, LANES), round_up(block_k, LANES)
     m, kk = a.shape
     _, n = b.shape
     ident = IDENTITY[semiring]
-    mp = _round_up(m, block_m)
-    np_ = _round_up(n, block_n)
-    kp = _round_up(kk, block_k)
+    mp = round_up(m, block_m)
+    np_ = round_up(n, block_n)
+    kp = round_up(kk, block_k)
     ap = jnp.pad(a.astype(jnp.float32), ((0, mp - m), (0, kp - kk)),
                  constant_values=ident)
     bp = jnp.pad(b.astype(jnp.float32), ((0, kp - kk), (0, np_ - n)),
@@ -108,7 +114,7 @@ def semiring_matmul_pallas(a: jax.Array, b: jax.Array,
             pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        out_shape=out_struct((mp, np_), jnp.float32, ap, bp),
         interpret=interpret,
     )(ap, bp)
     return out[:m, :n]

@@ -56,12 +56,11 @@ def _interpret() -> bool:
 
 
 def _resolve(impl: str | None, order_sensitive: bool, assume_exact: bool) -> str:
+    # an explicit impl is taken verbatim: pair_count also knows "matmul"
     if impl is not None:
         return impl
-    resolved = _backend().resolve(None)
-    if resolved == "pallas" and order_sensitive and not assume_exact:
-        return "xla"
-    return resolved
+    return _backend().resolve(order_sensitive=order_sensitive,
+                              assume_exact=assume_exact)
 
 
 def segment_reduce(values: jax.Array, segment_ids: jax.Array,

@@ -9,14 +9,17 @@ pairs, or any §5.4-style co-occurrence count:
 The systolic MXU *is* the counter — no hash map, no scatter; the paper's
 worst-case collision pathology disappears by construction.
 
-Tiling follows ``kernels.dfg_count`` (which is now a thin square-case
-wrapper over this kernel): the event stream is cut into ``block_e`` tiles
-(grid axis k, the reduction axis — innermost, so each output block
-accumulates in VMEM across iterations); the (S, D) count matrix is cut
-into ``block_s x block_d`` output tiles (grid axes i, j).  Accumulation is
-float32 on the MXU — exact for integer-valued weights while per-cell sums
-stay < 2^24; the dispatch layer routes inexact-float weights to the XLA
-scatter unless told otherwise.
+Layout: the event stream is one lane-dense ``(1, E)`` row cut into
+``(1, block_e)`` tiles (grid axis k, the reduction axis — innermost, so
+each output block accumulates in VMEM across iterations).  Each tile
+builds its one-hots already transposed — ``(block_s, block_e)`` and
+``(block_d, block_e)``, events on lanes, ids on sublanes — so no event
+vector is ever reshaped into a column, and the MXU contracts the shared
+event axis (an NT matmul).  The (S, D) count matrix is cut into
+``block_s x block_d`` output tiles (grid axes i, j).  Accumulation is
+float32 at full (``HIGHEST``) MXU precision — exact for integer-valued
+weights while per-cell sums stay < 2^24; the dispatch layer routes
+inexact-float weights to the XLA scatter unless told otherwise.
 """
 from __future__ import annotations
 
@@ -25,6 +28,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.tiles import event_row, lane_tile, out_struct, round_up
+
+# contract the event (lane) axis of both transposed one-hots: (S, E) x (D, E)
+_NT = (((1,), (1,)), ((), ()))
 
 
 def _kernel(src_ref, dst_ref, w_ref, out_ref, *, block_s, block_d):
@@ -36,19 +44,17 @@ def _kernel(src_ref, dst_ref, w_ref, out_ref, *, block_s, block_d):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    s = src_ref[...].reshape(-1, 1)            # (block_e, 1)
-    d = dst_ref[...].reshape(-1, 1)
-    w = w_ref[...].reshape(-1, 1)
-    be = s.shape[0]
-    rows_s = jax.lax.broadcasted_iota(jnp.int32, (be, block_s), 1)
-    rows_d = jax.lax.broadcasted_iota(jnp.int32, (be, block_d), 1)
-    x = jnp.where(s == rows_s + i * block_s, w, 0.0)             # (be, S_i)
-    y = jnp.where(d == rows_d + j * block_d, 1.0, 0.0)           # (be, D_j)
-    out_ref[...] += jnp.dot(x.T, y, preferred_element_type=jnp.float32)
-
-
-def _round_up(x: int, m: int) -> int:
-    return max(m, ((x + m - 1) // m) * m)
+    s = src_ref[...]                           # (1, block_e) lane-dense
+    d = dst_ref[...]
+    w = w_ref[...]
+    be = s.shape[1]
+    ids_s = jax.lax.broadcasted_iota(jnp.int32, (block_s, be), 0) + i * block_s
+    ids_d = jax.lax.broadcasted_iota(jnp.int32, (block_d, be), 0) + j * block_d
+    xt = jnp.where(ids_s == s, w, 0.0)                           # (S_i, be)
+    yt = jnp.where(ids_d == d, 1.0, 0.0)                         # (D_j, be)
+    out_ref[...] += jax.lax.dot_general(
+        xt, yt, _NT, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("num_src", "num_dst", "block_e",
@@ -59,28 +65,29 @@ def pair_count_pallas(src: jax.Array, dst: jax.Array, w: jax.Array,
                       block_d: int = 128, interpret: bool = True) -> jax.Array:
     """(num_src, num_dst) float32 weighted pair counts (OOB dropped).
 
+    ``block_e`` is rounded up to whole 128-lane vregs, ``block_s`` to
+    whole 8-row sublane groups and ``block_d`` to whole 128-lane vregs.
     Padding events carry w == 0; the caller masks invalid pairs the same way.
     """
     e = src.shape[0]
     if e == 0:
         return jnp.zeros((num_src, num_dst), jnp.float32)
-    pad_e = (-e) % block_e
-    s_pad, d_pad = _round_up(num_src, block_s), _round_up(num_dst, block_d)
-    srcp = jnp.pad(src.astype(jnp.int32), (0, pad_e), constant_values=-1)
-    dstp = jnp.pad(dst.astype(jnp.int32), (0, pad_e), constant_values=-1)
-    wp = jnp.pad(w.astype(jnp.float32), (0, pad_e))
-    ne = (e + pad_e) // block_e
+    be = lane_tile(block_e)
+    bs = round_up(min(block_s, round_up(num_src, 8)), 8)
+    bd = round_up(block_d, 128)
+    s_pad, d_pad = round_up(num_src, bs), round_up(num_dst, bd)
+    srcp = event_row(src.astype(jnp.int32), be, -1)
+    dstp = event_row(dst.astype(jnp.int32), be, -1)
+    wp = event_row(w.astype(jnp.float32), be, 0)
+    ne = srcp.shape[1] // be
 
+    event_spec = pl.BlockSpec((1, be), lambda i, j, k: (0, k))
     out = pl.pallas_call(
-        functools.partial(_kernel, block_s=block_s, block_d=block_d),
-        grid=(s_pad // block_s, d_pad // block_d, ne),
-        in_specs=[
-            pl.BlockSpec((block_e,), lambda i, j, k: (k,)),
-            pl.BlockSpec((block_e,), lambda i, j, k: (k,)),
-            pl.BlockSpec((block_e,), lambda i, j, k: (k,)),
-        ],
-        out_specs=pl.BlockSpec((block_s, block_d), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((s_pad, d_pad), jnp.float32),
+        functools.partial(_kernel, block_s=bs, block_d=bd),
+        grid=(s_pad // bs, d_pad // bd, ne),
+        in_specs=[event_spec, event_spec, event_spec],
+        out_specs=pl.BlockSpec((bs, bd), lambda i, j, k: (i, j)),
+        out_shape=out_struct((s_pad, d_pad), jnp.float32, srcp, dstp, wp),
         interpret=interpret,
     )(srcp, dstp, wp)
     return out[:num_src, :num_dst]

@@ -109,7 +109,8 @@ def pair_count_matmul(src: jax.Array, dst: jax.Array, w: jax.Array,
         s, d, ww = xs
         x = jax.nn.one_hot(s, num_src, dtype=jnp.float32) * ww[:, None]
         y = jax.nn.one_hot(d, num_dst, dtype=jnp.float32)
-        return c + jnp.dot(x.T, y, preferred_element_type=jnp.float32), None
+        return c + jnp.dot(x.T, y, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32), None
 
     c, _ = jax.lax.scan(
         body, jnp.zeros((num_src, num_dst), jnp.float32),

@@ -75,9 +75,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     rules = make_rules(mesh, cfg, seq_parallel=cfg.seq_parallel)
     rules = _batch_rules(rules, mesh, shape.batch)
 
-    from repro.compat import set_mesh
-
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             oc = OptConfig()
             step = TS.make_train_step(cfg, rules, oc, num_microbatches)

@@ -7,7 +7,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.module import ShardingRules
 
@@ -15,7 +14,8 @@ from repro.models.module import ShardingRules
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_rules(mesh, cfg: ModelConfig, *, seq_parallel: bool = False) -> ShardingRules:

@@ -23,9 +23,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from .config import ModelConfig
 from .module import ShardingRules
@@ -80,9 +79,7 @@ def _local_moe(xt, router, gate, up, down, *, cfg: ModelConfig, model_axis,
 def moe_apply_ep(p, x, cfg: ModelConfig, rules: ShardingRules):
     """shard_map expert-parallel MoE. Requires an ambient mesh whose model
     axis divides num_experts; falls back to the dense path otherwise."""
-    from repro.compat import get_abstract_mesh
-
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or "model" not in mesh.axis_names:
         from . import layers as L
         return L.moe_apply_dense(p, x, cfg, rules)

@@ -425,6 +425,8 @@ def main(argv=None) -> None:
         python -m repro.service.server --dir /data/parts \\
             --ingest-from /data/batches --port 8099
     """
+    from repro.core.backend import enable_compile_cache
+
     from .ingest import Ingestor
 
     ap = argparse.ArgumentParser(description=main.__doc__)
@@ -437,6 +439,7 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if not args.dir:
         ap.error("--dir (or REPRO_SERVICE_DIR) is required")
+    enable_compile_cache()
     source: object = args.dir
     ingestor = None
     if args.ingest_from:

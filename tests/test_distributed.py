@@ -116,7 +116,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.train import compression
 
 mesh = jax.sharding.Mesh(np.array(jax.devices()), ("pod",))

@@ -111,6 +111,30 @@ def test_semiring_matmul_pallas_equals_ref_bitwise(semiring, shape):
     assert np.array_equal(got_p, oracle.astype(np.float32))
 
 
+def test_plus_times_float_gate(monkeypatch):
+    """An inexact-float ``plus_times`` product sums in an order each
+    lowering picks, so under the pallas backend it still takes the XLA
+    reference unless the caller asserts exact operands (the boolean
+    closure does) — the segment primitives' float guardrail."""
+    from repro.kernels.graph_ops import ops as gops
+
+    called = []
+
+    def fake_pallas(a, b, semiring, **kw):
+        called.append(semiring)
+        return semiring_matmul_ref(a, b, semiring)
+
+    monkeypatch.setattr(gops, "semiring_matmul_pallas", fake_pallas)
+    p = jnp.full((3, 3), 1.0 / 3.0, jnp.float32)
+    with backend.use_backend("pallas"):
+        gops.semiring_matmul(p, p, "plus_times")
+        assert called == []
+        gops.semiring_matmul(p, p, "plus_times", assume_exact=True)
+        gops.semiring_matmul(p, p, "min_plus")
+        bool_closure(jnp.eye(3, dtype=bool), 2)
+    assert called[:2] == ["plus_times", "min_plus"] and len(called) >= 3
+
+
 def test_closures_match_host_oracles_under_both_backends():
     rng = np.random.default_rng(17)
     n = 11

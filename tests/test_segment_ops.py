@@ -181,6 +181,11 @@ def test_scan_carry_chains_across_chunks():
 def test_backend_dispatch_and_float_gate():
     assert backend.resolve("pallas") == "pallas"
     assert backend.resolve("xla") == "xla"
+    with backend.use_backend("pallas"):
+        assert backend.resolve(order_sensitive=True) == "xla"
+        assert backend.resolve(order_sensitive=True,
+                               assume_exact=True) == "pallas"
+        assert backend.resolve("pallas", order_sensitive=True) == "pallas"
     with backend.use_backend("xla"):
         assert backend.get_backend() == "xla"
     with pytest.raises(ValueError):
