@@ -39,44 +39,27 @@ ROOT = Path(__file__).resolve().parent
 ROW_GROUP_ROWS = 100_000       # EDF row group: the streaming engine's unit
 LEVEL = 1                      # paper Table 6 L1
 CORE_VERBS = ("dfg", "variants", "alpha")
-# one event per executable: tracing and lowering nest (an outer jit traces
-# its inner ones), so their durations are left in the run share
-COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
-                  "/jax/compilation_cache/cache_retrieval_time_sec")
 
 
 class Clock:
-    """Per-phase wall time, split into compilation (JAX's backend-compile
-    and compile-cache retrieval events) and the rest, plus the persistent
-    compile cache's hits and misses."""
+    """Per-phase wall time and the programs compiled or loaded from the
+    persistent compile cache in it (``repro.obs`` compile counts of the
+    phase's thread and of the scans it runs)."""
 
-    def __init__(self, jax):
-        self._lock = threading.Lock()
-        self.compile_s = 0.0
-        self.cache = {"hits": 0, "misses": 0}
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event in COMPILE_EVENTS:
-            with self._lock:
-                self.compile_s += duration
-
-    def _event(self, event, **_):
-        key = {"/jax/compilation_cache/cache_hits": "hits",
-               "/jax/compilation_cache/cache_misses": "misses"}.get(event)
-        if key:
-            with self._lock:
-                self.cache[key] += 1
+    def __init__(self):
+        self.compiles = 0
 
     @contextlib.contextmanager
     def phase(self, name):
-        c0, t0 = self.compile_s, time.perf_counter()
-        yield
+        from repro import obs
+
+        t0 = time.perf_counter()
+        with obs.record() as rec:
+            yield
         wall = time.perf_counter() - t0
-        comp = self.compile_s - c0
-        print(f"phase {name}: wall_s={wall} compile_s={comp} "
-              f"run_s={wall - comp}", flush=True)
+        n = sum(rec.compiles.values())
+        self.compiles += n
+        print(f"phase {name}: wall_s={wall} compiles={n}", flush=True)
 
 
 def canon(result) -> str:
@@ -334,7 +317,7 @@ def main(argv=None) -> None:
                          f"(interpret={backend.interpret_mode()}); the chip "
                          f"path needs compiled Pallas kernels")
     cache_dir = backend.enable_compile_cache()
-    clock = Clock(jax)
+    clock = Clock()
     print(f"device: {dev.device_kind} x{len(jax.devices())}; lowering: "
           f"{backend.resolve()} interpret={backend.interpret_mode()}; "
           f"compile cache: {cache_dir}", flush=True)
@@ -346,9 +329,7 @@ def main(argv=None) -> None:
         stats = d.memory_stats() or {}
         print(f"device {d.id}: peak_bytes_in_use="
               f"{stats.get('peak_bytes_in_use')}", flush=True)
-    print(f"compile cache: hits={clock.cache['hits']} "
-          f"misses={clock.cache['misses']} total_compile_s={clock.compile_s}",
-          flush=True)
+    print(f"compiles: {clock.compiles}", flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

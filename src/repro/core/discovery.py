@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.segment_ops import pair_count
 
 from .eventframe import ACTIVITY, CASE, EventFrame
@@ -150,11 +151,11 @@ def discover_alpha(d: DFG, min_count: int = 1) -> AlphaModel:
     """Alpha miner over an accumulated DFG state (whole-log, streamed, or
     psum-merged — the miner is pure finalize, it never sees events)."""
     fp = footprint(d, min_count)
-    causal = np.asarray(fp.causal)
-    choice = np.asarray(fp.choice)
+    causal = obs.pull(fp.causal)
+    choice = obs.pull(fp.choice)
     places = _maximal_pairs(causal, choice)
-    starts = frozenset(int(i) for i in np.nonzero(np.asarray(d.starts))[0])
-    ends = frozenset(int(i) for i in np.nonzero(np.asarray(d.ends))[0])
+    starts = frozenset(int(i) for i in np.nonzero(obs.pull(d.starts))[0])
+    ends = frozenset(int(i) for i in np.nonzero(obs.pull(d.ends))[0])
     return AlphaModel(num_activities=d.num_activities, places=places,
                       start_activities=starts, end_activities=ends,
                       footprint=fp)
@@ -245,8 +246,8 @@ def discover_heuristics(state: "DiscoveryState | DFG",
         d.counts, l2c, dep, l2, and_m,
         jnp.float32(dependency_threshold), jnp.float32(l2_threshold),
         jnp.int32(min_count), jnp.float32(and_threshold))
-    starts = frozenset(int(i) for i in np.nonzero(np.asarray(d.starts))[0])
-    ends = frozenset(int(i) for i in np.nonzero(np.asarray(d.ends))[0])
+    starts = frozenset(int(i) for i in np.nonzero(obs.pull(d.starts))[0])
+    ends = frozenset(int(i) for i in np.nonzero(obs.pull(d.ends))[0])
     return HeuristicsNet(dependency=dep, l2=l2, graph=graph,
                          and_bindings=and_b, start_activities=starts,
                          end_activities=ends)

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from . import polyhash
 from .eventframe import ACTIVITY, CASE, TIMESTAMP, EventFrame
 
@@ -269,11 +271,13 @@ def global_segments(adj: Adjacent, carry: Carry) -> jax.Array:
 def run_streaming(kernel: ChunkKernel, chunks: Iterable[Chunk]):
     """Fold a kernel over an ordered chunk stream; O(chunk) residency."""
     state, carry = kernel.init()
-    for chunk in chunks:
+    for i, chunk in enumerate(chunks):
         if chunk.nrows == 0:        # empty source / empty tail group
             continue
-        state, carry = kernel.update(state, carry, chunk)
-    return kernel.finalize(state, carry)
+        with obs.span("fold.update", group=i):
+            state, carry = kernel.update(state, carry, chunk)
+    with obs.span("fold.finalize"):
+        return kernel.finalize(state, carry)
 
 
 def run_single(kernel: ChunkKernel, frame: Chunk):
@@ -376,6 +380,11 @@ def fold_group(kernel: ChunkKernel, chunks: Iterable[Chunk]) -> GroupState:
     histogram stays empty) and their sketch columns supply the lead run's
     composed affine map.
     """
+    with obs.span("fold.group"):
+        return _fold_group(kernel, chunks)
+
+
+def _fold_group(kernel: ChunkKernel, chunks: Iterable[Chunk]) -> GroupState:
     state, carry = kernel.init()
     segments = 0
     rows = 0
@@ -389,9 +398,9 @@ def fold_group(kernel: ChunkKernel, chunks: Iterable[Chunk]) -> GroupState:
         n = int(chunk.nrows)
         if n == 0:
             continue
-        case = np.asarray(chunk[CASE])
-        act = np.asarray(chunk[ACTIVITY])
-        rv = np.asarray(chunk.rows_valid())
+        case = obs.pull(chunk[CASE])
+        act = obs.pull(chunk[ACTIVITY])
+        rv = obs.pull(chunk.rows_valid())
         cont = rows > 0 and int(case[0]) == tail["case"]
         changes = np.flatnonzero(case[1:] != case[:-1])
         segments += 1 + int(changes.size) - (1 if cont else 0)
@@ -409,10 +418,10 @@ def fold_group(kernel: ChunkKernel, chunks: Iterable[Chunk]) -> GroupState:
             for a_id in np.flatnonzero(counts):
                 hist[int(a_id)] = hist.get(int(a_id), 0) + int(counts[a_id])
             if polyhash.SK_MUL1 in chunk:
-                m1 = np.asarray(chunk[polyhash.SK_MUL1])[:k]
-                a1 = np.asarray(chunk[polyhash.SK_ADD1])[:k]
-                m2 = np.asarray(chunk[polyhash.SK_MUL2])[:k]
-                a2 = np.asarray(chunk[polyhash.SK_ADD2])[:k]
+                m1 = obs.pull(chunk[polyhash.SK_MUL1])[:k]
+                a1 = obs.pull(chunk[polyhash.SK_ADD1])[:k]
+                m2 = obs.pull(chunk[polyhash.SK_MUL2])[:k]
+                a2 = obs.pull(chunk[polyhash.SK_ADD2])[:k]
                 for i in np.flatnonzero((m1 != 1) | (a1 != 0)
                                         | (m2 != 1) | (a2 != 0)):
                     affine = _compose4(affine, (int(m1[i]), int(a1[i]),
@@ -425,7 +434,8 @@ def fold_group(kernel: ChunkKernel, chunks: Iterable[Chunk]) -> GroupState:
                                             int(sk["add2"][0])))
             if changes.size:
                 lead_open = False
-        state, carry = kernel.update(state, carry, chunk)
+        with obs.span("fold.update"):
+            state, carry = kernel.update(state, carry, chunk)
         rows += n
         tail = {"case": int(case[-1]), "act": int(act[-1]), "rv": bool(rv[-1])}
     if rows == 0:
@@ -516,18 +526,20 @@ def merge_tree(kernel: ChunkKernel, states: Iterable[GroupState]) -> GroupState:
     level = [s for s in states if s is not None and s.rows > 0]
     if not level:
         return empty_group_state(kernel)
-    while len(level) > 1:
-        nxt = [merge_group_states(kernel, level[i], level[i + 1])
-               for i in range(0, len(level) - 1, 2)]
-        if len(level) % 2:
-            nxt.append(level[-1])
-        level = nxt
+    with obs.span("fold.merge"):
+        while len(level) > 1:
+            nxt = [merge_group_states(kernel, level[i], level[i + 1])
+                   for i in range(0, len(level) - 1, 2)]
+            if len(level) % 2:
+                nxt.append(level[-1])
+            level = nxt
     return level[0]
 
 
 def finalize_group(kernel: ChunkKernel, gs: GroupState):
     """Terminal step of the algebra: the kernel's ordinary ``finalize``."""
-    return kernel.finalize(gs.state, gs.carry)
+    with obs.span("fold.finalize"):
+        return kernel.finalize(gs.state, gs.carry)
 
 
 def union_columns(column_sets: Iterable[tuple]) -> tuple:
@@ -556,6 +568,7 @@ def compose(kernels: Mapping[str, ChunkKernel]) -> ChunkKernel:
     sketch-consuming member is enough for ghost chunks to carry sketches.
     """
     names = tuple(kernels)
+    spans = {k: f"fold.update.{k}" for k in names}
 
     def init():
         pairs = {k: kernels[k].init() for k in names}
@@ -565,7 +578,9 @@ def compose(kernels: Mapping[str, ChunkKernel]) -> ChunkKernel:
     def update(state, carry, chunk):
         out_s, out_c = {}, {}
         for k in names:
-            out_s[k], out_c[k] = kernels[k].update(state[k], carry[k], chunk)
+            with obs.span(spans[k]):
+                out_s[k], out_c[k] = kernels[k].update(state[k], carry[k],
+                                                       chunk)
         return out_s, out_c
 
     def merge(a, b):

@@ -43,6 +43,7 @@ from typing import Any, Iterable, Mapping
 
 import numpy as np
 
+from repro import obs
 from repro.core import engine as _engine
 from repro.core.eventframe import TIMESTAMP, EventFrame
 
@@ -230,13 +231,16 @@ class Windows:
 
         bounds = self.bounds()
         if _engine.mergeable(kernel):
-            states, report = group_states(
-                self.dataset.plan(columns=kernel.columns), kernel, spec_fp)
-            results = []
-            for lo, hi in bounds:
-                merged = _engine.merge_tree(kernel, states[lo:hi])
-                out = _engine.finalize_group(kernel, merged)
-                results.append(post(out) if post else out)
+            with obs.record() as rec, obs.span("scan"):
+                states, report = group_states(
+                    self.dataset.plan(columns=kernel.columns), kernel,
+                    spec_fp)
+                results = []
+                for lo, hi in bounds:
+                    merged = _engine.merge_tree(kernel, states[lo:hi])
+                    out = _engine.finalize_group(kernel, merged)
+                    results.append(post(out) if post else out)
+            obs.add(report, rec)
             return results, bounds, report
         # no stitch: each window folds its rows sequentially from scratch
         units, physicals = self._units(kernel.columns)

@@ -115,6 +115,7 @@ def semiring_matmul_pallas(a: jax.Array, b: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=out_struct((mp, np_), jnp.float32, ap, bp),
+        name="semiring_matmul_pallas",
         interpret=interpret,
     )(ap, bp)
     return out[:m, :n]

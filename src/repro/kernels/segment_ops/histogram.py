@@ -71,6 +71,7 @@ def histogram_pallas(values: jax.Array, weights: jax.Array, num_bins: int, *,
         in_specs=[event_spec, event_spec],
         out_specs=pl.BlockSpec((bb, LANES), lambda i, k: (i, 0)),
         out_shape=out_struct((b_pad, LANES), weights.dtype, val, w),
+        name="histogram_pallas",
         interpret=interpret,
     )(val, w)
     return partial.sum(axis=1, dtype=weights.dtype)[:num_bins]

@@ -88,6 +88,7 @@ def pair_count_pallas(src: jax.Array, dst: jax.Array, w: jax.Array,
         in_specs=[event_spec, event_spec, event_spec],
         out_specs=pl.BlockSpec((bs, bd), lambda i, j, k: (i, j)),
         out_shape=out_struct((s_pad, d_pad), jnp.float32, srcp, dstp, wp),
+        name="pair_count_pallas",
         interpret=interpret,
     )(srcp, dstp, wp)
     return out[:num_src, :num_dst]

@@ -156,6 +156,7 @@ def segment_reduce_pallas(values: jax.Array, segment_ids: jax.Array,
             out_specs=window_spec),
         out_shape=out_struct((s_rows, LANES), vals.dtype, seg, val, init),
         input_output_aliases={4: 0},
+        name="segment_reduce_pallas",
         interpret=interpret,
     )(first, last, seg, val, init)
     out = out.reshape(-1)[:num_segments]

@@ -121,6 +121,7 @@ def segmented_affine_pallas(mul: jax.Array, add: jax.Array,
         out_specs=[tile, resident],
         out_shape=[out_struct(m.shape, jnp.int32, m, b, f, c0),
                    out_struct((1, 1), jnp.int32, m, b, f, c0)],
+        name="segmented_affine_pallas",
         interpret=interpret,
     )(m, b, f, c0)
     return _from_int32(ys[0, :n], dtype), _from_int32(cout[0, 0], dtype)
@@ -167,6 +168,7 @@ def segmented_sum_scan_pallas(values: jax.Array, seg_starts: jax.Array,
         out_specs=[pl.BlockSpec((kdim, w), lambda t: (0, t)), resident],
         out_shape=[out_struct(v.shape, vals.dtype, v, f, c0),
                    out_struct((kdim, 1), vals.dtype, v, f, c0)],
+        name="segmented_sum_scan_pallas",
         interpret=interpret,
     )(v, f, c0)
     ys = ys[:, :n].T

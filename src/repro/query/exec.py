@@ -65,6 +65,7 @@ import threading
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import engine
 from repro.core.chunked import ChunkedEventFrame
 from repro.core.eventframe import ACTIVITY, CASE, EventFrame
@@ -80,7 +81,10 @@ from .plan import MultiPlan, Plan
 @dataclasses.dataclass
 class ScanReport:
     """I/O accounting for one executed plan (all byte counts are on-disk
-    compressed extents of the scan's projected column set)."""
+    compressed extents of the scan's projected column set), plus the
+    scan's time and sync counters (``repro.obs``): ``timings`` ``{span:
+    (count, total_s, self_s)}``, ``host_syncs``, ``bytes_to_device`` and
+    ``compiles`` ``{fun_name: count}``."""
 
     path: str
     columns: tuple
@@ -99,6 +103,10 @@ class ScanReport:
     phase1_groups_read: int = 0
     phase1_bytes_read: int = 0
     per_file: tuple = ()        # multi-file plans: the per-file reports
+    timings: dict = dataclasses.field(default_factory=dict)
+    host_syncs: int = 0
+    bytes_to_device: int = 0
+    compiles: dict = dataclasses.field(default_factory=dict)
 
     @property
     def skip_ratio(self) -> float:
@@ -135,6 +143,8 @@ def merge_reports(reports) -> ScanReport:
               "rows_total", "rows_read", "bytes_total",
               "bytes_read", "phase1_groups_read", "phase1_bytes_read"):
         setattr(out, f, sum(getattr(r, f) for r in reports))
+    for r in reports:
+        obs.add(out, r)
     return out
 
 
@@ -168,20 +178,20 @@ def prefetch_depth(prefetch: int | None = None) -> int:
     return max(int(prefetch), 0)
 
 
-_DONE = object()
-
-
 def _read_ahead(reader: EDFReader, schedule, read_columns, depth: int):
     """Yield ``(item, frame | None)`` pairs in schedule order, fetching and
     decoding up to ``depth`` read groups ahead on a daemon thread (the
     double buffer: group *g+1* decompresses while the kernel runs on *g*).
     Ghost items pass through with ``frame=None`` — their synthesis is
-    order-dependent and stays on the consumer.  Worker exceptions re-raise
-    at the consumer's matching position; an abandoned consumer (generator
-    closed early) stops the worker via the stop event + queue drain, so no
-    thread is ever left blocked on a full queue."""
+    order-dependent and stays on the consumer.  The worker serves the
+    consumer's bound ``obs`` record; the consumer's waits are the
+    ``scan.wait`` spans.  Worker exceptions re-raise at the consumer's
+    matching position; an abandoned consumer (generator closed early)
+    stops the worker via the stop event + queue drain, so no thread is
+    ever left blocked on a full queue."""
     q: queue.Queue = queue.Queue(maxsize=depth)
     stop = threading.Event()
+    rec = obs.current()
 
     def _put(payload) -> bool:
         while not stop.is_set():
@@ -193,27 +203,27 @@ def _read_ahead(reader: EDFReader, schedule, read_columns, depth: int):
         return False
 
     def worker():
-        try:
-            for item in schedule:
-                if isinstance(item, GhostItem):
-                    out = (item, None)
-                elif stop.is_set():
-                    return
-                else:
-                    out = (item, reader.read_group(item.index, read_columns))
-                if not _put(out):
-                    return
-            _put(_DONE)
-        except BaseException as exc:  # noqa: BLE001 — re-raised at consumer
-            _put(exc)
+        with obs.bind(rec):
+            try:
+                for item in schedule:
+                    if isinstance(item, GhostItem):
+                        out = (item, None)
+                    elif stop.is_set():
+                        return
+                    else:
+                        out = (item, reader.read_group(item.index,
+                                                       read_columns))
+                    if not _put(out):
+                        return
+            except BaseException as exc:  # noqa: BLE001 — re-raised at consumer
+                _put(exc)
 
     t = threading.Thread(target=worker, daemon=True, name="repro-prefetch")
     t.start()
     try:
-        while True:
-            got = q.get()
-            if got is _DONE:
-                return
+        for item in schedule:
+            with obs.span("scan.wait", group=getattr(item, "index", -1)):
+                got = q.get()
             if isinstance(got, BaseException):
                 raise got
             yield got
@@ -304,23 +314,25 @@ def _masked_chunks(pairs, reader, steps, keeps, chunk_columns, read_columns,
             continue
         if frame is None:
             frame = reader.read_group(item.index, read_columns)
-        mask = np.ones(frame.nrows, bool)
-        for pos in item.residual:
-            mask &= np.asarray(steps[pos].mask(frame), bool)
-        if CASE in frame and frame.nrows:
-            case = np.asarray(frame[CASE])
-            if track_segs:
-                new0 = prev_case is None or case[0] != prev_case
-                seg = last_seg + int(new0) + np.concatenate(
-                    [[0], np.cumsum(case[1:] != case[:-1])])
-                for pos in item.case_steps:
-                    keep = keeps[pos]
-                    seg_c = np.minimum(seg, len(keep) - 1)
-                    mask &= keep[seg_c] & (seg < len(keep))
-                last_seg = int(seg[-1])
-            prev_case = case[-1]
-        sel = frame.select(chunk_columns)
-        yield EventFrame(sel.columns, sel.valid, jnp.asarray(mask))
+        with obs.span("scan.mask", group=item.index):
+            mask = np.ones(frame.nrows, bool)
+            for pos in item.residual:
+                mask &= obs.pull(steps[pos].mask(frame), bool)
+            if CASE in frame and frame.nrows:
+                case = obs.pull(frame[CASE])
+                if track_segs:
+                    new0 = prev_case is None or case[0] != prev_case
+                    seg = last_seg + int(new0) + np.concatenate(
+                        [[0], np.cumsum(case[1:] != case[:-1])])
+                    for pos in item.case_steps:
+                        keep = keeps[pos]
+                        seg_c = np.minimum(seg, len(keep) - 1)
+                        mask &= keep[seg_c] & (seg < len(keep))
+                    last_seg = int(seg[-1])
+                prev_case = case[-1]
+            sel = frame.select(chunk_columns)
+            chunk = EventFrame(sel.columns, sel.valid, jnp.asarray(mask))
+        yield chunk
 
 
 def _base_report(physical: PhysicalPlan) -> ScanReport:
@@ -504,7 +516,7 @@ def _multi_phase1(physicals, reports, offsets, total,
                                         prefetch)
 
         result = engine.run_streaming(kern, gen())
-        keeps[pos] = np.asarray(step.finalize_keep(result), bool)
+        keeps[pos] = obs.pull(step.finalize_keep(result), bool)
     return keeps
 
 
@@ -613,7 +625,7 @@ def _single_pass_source(physicals, reports, offsets, total, sk_keeps,
             if dirty:
                 for pos in data_pos:
                     st, ca = states[pos]
-                    finals[pos] = np.asarray(steps[pos].finalize_keep(
+                    finals[pos] = obs.pull(steps[pos].finalize_keep(
                         kernels[pos].finalize(st, ca)), bool)
                 dirty = False
             return {**sk_keeps, **finals}
@@ -646,8 +658,8 @@ def _single_pass_source(physicals, reports, offsets, total, sk_keeps,
                     rep.groups_proved += 1
             mask = np.ones(frame.nrows, bool)
             for i in residual:
-                mask &= np.asarray(steps[i].mask(frame), bool)
-            case = np.asarray(frame[CASE])
+                mask &= obs.pull(steps[i].mask(frame), bool)
+            case = obs.pull(frame[CASE])
             seg = glo + np.concatenate(
                 [[0], np.cumsum(case[1:] != case[:-1])])
             for p in case_pos:
@@ -663,7 +675,7 @@ def _single_pass_source(physicals, reports, offsets, total, sk_keeps,
                 else:
                     mask = np.ones(frame.nrows, bool)
                     for i in key:
-                        mask &= np.asarray(steps[i].mask(frame), bool)
+                        mask &= obs.pull(steps[i].mask(frame), bool)
                     cache[key] = EventFrame(frame.columns, frame.valid,
                                             jnp.asarray(mask))
             return cache[key]
@@ -847,10 +859,13 @@ def execute(plan: "Plan | MultiPlan", mine: engine.ChunkKernel, *,
     ``prune=False`` executes the identical plan without zone-map skipping
     (the full-scan baseline the benchmarks compare against).
     """
-    src, report = pruned_source(
-        plan, prune=prune, mask_exact=getattr(mine, "mask_exact", True),
-        sketch=getattr(mine, "ghost_sketch", False), prefetch=prefetch)
-    return engine.run_streaming(mine, src), report
+    with obs.record() as rec, obs.span("scan"):
+        src, report = pruned_source(
+            plan, prune=prune, mask_exact=getattr(mine, "mask_exact", True),
+            sketch=getattr(mine, "ghost_sketch", False), prefetch=prefetch)
+        result = engine.run_streaming(mine, src)
+    obs.add(report, rec)
+    return result, report
 
 
 # -------------------------------------------------- group-state execution
@@ -927,19 +942,22 @@ def group_states(plan: "Plan | MultiPlan", kernel: engine.ChunkKernel,
                 continue
             g = item.index
             key = _unit_key(ph, item, spec_fp)
-            hit = cache.get(key)
+            with obs.span("cache.lookup", group=g):
+                hit = cache.get(key)
             if hit is not None:
                 rep.groups_cached += 1
                 states.append(hit)
                 continue
             frame = ph.reader.read_group(g, ph.read_columns)
-            mask = np.ones(frame.nrows, bool)
-            for i in item.residual:
-                mask &= np.asarray(steps[i].mask(frame), bool)
-            sel = frame.select(ph.chunk_columns)
-            gs = fold_group(kernel, [EventFrame(sel.columns, sel.valid,
-                                                jnp.asarray(mask))])
-            cache.put(key, gs)
+            with obs.span("scan.mask", group=g):
+                mask = np.ones(frame.nrows, bool)
+                for i in item.residual:
+                    mask &= obs.pull(steps[i].mask(frame), bool)
+                sel = frame.select(ph.chunk_columns)
+                chunk = EventFrame(sel.columns, sel.valid, jnp.asarray(mask))
+            gs = fold_group(kernel, [chunk])
+            with obs.span("cache.lookup", group=g):
+                cache.put(key, gs)
             states.append(gs)
             rep.groups_folded += 1
             rep.groups_read += 1
@@ -961,9 +979,12 @@ def execute_grouped(plan: "Plan | MultiPlan", kernel: engine.ChunkKernel,
     after appending a file (or new groups) only decodes what the state
     cache has not seen.  Returns ``(result, report)``.
     """
-    states, report = group_states(plan, kernel, spec_fp, prune=prune)
-    merged = engine.merge_tree(kernel, states)
-    return engine.finalize_group(kernel, merged), report
+    with obs.record() as rec, obs.span("scan"):
+        states, report = group_states(plan, kernel, spec_fp, prune=prune)
+        merged = engine.merge_tree(kernel, states)
+        result = engine.finalize_group(kernel, merged)
+    obs.add(report, rec)
+    return result, report
 
 
 def grouped_cache_probe(plan: "Plan | MultiPlan", kernel: engine.ChunkKernel,
@@ -1025,17 +1046,21 @@ def execute_frame(plan: "Plan | MultiPlan", *, prune: bool = True,
     """
     if isinstance(plan, Plan):
         plan = MultiPlan((plan.path,), plan.steps, plan.projection)
-    physicals, reports, offsets, keeps = _multi_compile(plan, prune, prefetch)
-    schedules, locals_ = _multi_schedules(physicals, reports, offsets,
-                                          keeps, ghosts=False,
-                                          skippable=True)
-    depth = prefetch_depth(prefetch)
-    for rep in reports:
-        rep.prefetch = depth
-    parts = []
-    for ph, sched, lk in zip(physicals, schedules, locals_):
-        parts += [c.compact() for c in
-                  _iter_chunks(ph, sched, lk, ph.chunk_columns,
-                               ph.read_columns, depth)]
-    frame, tables = _materialize(parts, physicals[0])
-    return frame, tables, merge_reports(reports)
+    with obs.record() as rec, obs.span("scan"):
+        physicals, reports, offsets, keeps = _multi_compile(plan, prune,
+                                                            prefetch)
+        schedules, locals_ = _multi_schedules(physicals, reports, offsets,
+                                              keeps, ghosts=False,
+                                              skippable=True)
+        depth = prefetch_depth(prefetch)
+        for rep in reports:
+            rep.prefetch = depth
+        parts = []
+        for ph, sched, lk in zip(physicals, schedules, locals_):
+            parts += [c.compact() for c in
+                      _iter_chunks(ph, sched, lk, ph.chunk_columns,
+                                   ph.read_columns, depth)]
+        frame, tables = _materialize(parts, physicals[0])
+    report = merge_reports(reports)
+    obs.add(report, rec)
+    return frame, tables, report

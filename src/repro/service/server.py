@@ -52,6 +52,7 @@ from typing import Mapping
 
 import numpy as np
 
+from repro import obs
 from repro.storage import edf as _edf
 
 
@@ -376,9 +377,10 @@ class _Handler(BaseHTTPRequestHandler):
             return self._send(404, {"ok": False, "error":
                                     f"unknown endpoint {route!r}; one of "
                                     f"{sorted(handlers)}"})
-        t0 = time.perf_counter()
+        name = "http." + route.strip("/")
         try:
-            out = fn(**params) if route != "/health" else fn()
+            with obs.record() as rec, obs.span(name):
+                out = fn(**params) if route != "/health" else fn()
         except ServiceError as e:
             return self._send(e.status, {"ok": False, "error": str(e)})
         except (ValueError, KeyError, TypeError) as e:
@@ -388,7 +390,7 @@ class _Handler(BaseHTTPRequestHandler):
             return self._send(500, {"ok": False, "error":
                                     f"{type(e).__name__}: {e}"})
         out = {"ok": True, **out}
-        out["elapsed_us"] = (time.perf_counter() - t0) * 1e6
+        out["elapsed_us"] = rec.timings[name][1] * 1e6
         self._send(200, out)
 
     def _send(self, status: int, body: dict) -> None:

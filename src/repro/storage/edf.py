@@ -81,6 +81,7 @@ from typing import Iterable, Mapping
 
 import numpy as np
 
+from repro import obs
 from repro.core.eventframe import ACTIVITY, CASE, EventFrame
 from repro.core.polyhash import SKETCH_KEYS, segment_sketch
 
@@ -399,7 +400,7 @@ def append(path: str, frame: EventFrame,
     Returns the new header.  Thread-safe per path within this process;
     cross-process writers need external coordination.
     """
-    with _append_lock(path):
+    with obs.span("ingest.append"), _append_lock(path):
         return _append_locked(path, frame, tables, row_group_rows)
 
 
@@ -539,19 +540,26 @@ def _fetch_group_v2(f, base: int, header: dict, group: dict, want
     return fetched
 
 
-def _decode_group_v2(fetched: list[tuple], gn: int) -> EventFrame:
-    """Decompress + deserialize fetched extents (no file handle needed)."""
+def _decode_group_v2(fetched: list[tuple], gn: int,
+                     index: int = -1) -> EventFrame:
+    """Decompress + deserialize fetched extents (no file handle needed),
+    then put the columns on the device."""
     cols: dict[str, np.ndarray] = {}
     valid: dict[str, np.ndarray] = {}
-    for meta, ccodec, raw, vraw in fetched:
-        name = meta["name"]
-        buf = _decode(raw, ccodec)
-        cols[name] = np.frombuffer(buf, dtype=np.dtype(meta["dtype"])).copy()
-        if vraw is not None:
-            valid[name] = np.unpackbits(
-                np.frombuffer(_decode(vraw, ccodec), np.uint8),
-                count=gn).astype(bool)
-    return EventFrame.from_numpy(cols, valid)
+    with obs.span("edf.decode", group=index):
+        for meta, ccodec, raw, vraw in fetched:
+            name = meta["name"]
+            buf = _decode(raw, ccodec)
+            cols[name] = np.frombuffer(buf,
+                                       dtype=np.dtype(meta["dtype"])).copy()
+            if vraw is not None:
+                valid[name] = np.unpackbits(
+                    np.frombuffer(_decode(vraw, ccodec), np.uint8),
+                    count=gn).astype(bool)
+    with obs.span("edf.put", group=index):
+        obs.put(sum(a.nbytes for a in cols.values())
+                + sum(a.nbytes for a in valid.values()))
+        return EventFrame.from_numpy(cols, valid)
 
 
 def _read_group_v2(f, base: int, header: dict, group: dict, want):
@@ -808,10 +816,10 @@ class EDFReader:
         # decompression happens *outside* the lock, so concurrent scans
         # (or a prefetch thread) of the same pooled reader decode in
         # parallel instead of serializing on the handle
-        with self._io_lock:
+        with obs.span("edf.fetch", group=index), self._io_lock:
             fetched = _fetch_group_v2(self._fh(), self.base, self.header,
                                       group, want)
-        return _decode_group_v2(fetched, group["nrows"])
+        return _decode_group_v2(fetched, group["nrows"], index)
 
     def group_meta(self, index: int) -> dict:
         """``{"nrows", "zones", "segments"?, "tail"?}`` for one row group."""

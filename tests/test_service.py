@@ -465,7 +465,13 @@ def test_http_endpoints(tmp_path, log):
 
         health = get("/health")
         assert health["ok"] and health["rows"] == frame.nrows
+        t0 = time.perf_counter()
         got = get("/collect?verb=dfg&engine=streaming")
+        wall_us = (time.perf_counter() - t0) * 1e6
+        # the handler's own time: inside the client's, and covering the scan
+        assert 0 < got["elapsed_us"] <= wall_us
+        scan_us = got["report"]["timings"]["scan"][1] * 1e6
+        assert scan_us <= got["elapsed_us"]
         ref = repro.open(frame, tables=tables,
                          num_cases=got["snapshot"]["num_cases"]).collect(
                              "dfg", engine="eager")

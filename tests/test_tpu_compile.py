@@ -7,11 +7,15 @@ more VMEM than a kernel may use.  These tests compile each kernel with
 nothing runs — at the widths the chip smoke run uses: 100,000-row groups,
 26 activities, a case capacity of 10^6, and graph alphabets of 28 and 300.
 One more compiles the sharded engine's kernels inside ``shard_map`` over
-all four described chips.
+all four described chips.  Each also finds its kernel in the compiled
+module under the name ``pallas_call(name=)`` pins: the name the device
+trace shows, which the benchmark's roofline share matches.
 
 The topology is described inside a fixture, never at import time: only one
 process may load the TPU library, and test workers import every file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -58,20 +62,31 @@ def spec(topo):
     compilation_cache.reset_cache()
 
 
-def _compile(kernel, *shapes, **static):
-    hlo = kernel.lower(*shapes, interpret=False, **static).compile().as_text()
+def _named(hlo: str, name: str) -> bool:
+    """The compiled module holds an instruction named ``name`` (the name
+    the device trace shows and the benchmark's kernel table matches)."""
+    return re.search(rf"^\s*(ROOT )?%{name}(\.\d+)? = ", hlo, re.M) is not None
+
+
+def _compile(kernel, name, *shapes, **static):
+    lowered = kernel.lower(*shapes, interpret=False, **static)
+    # pinned by ``pallas_call(name=)``, not taken from a wrapper's name
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo        # the Mosaic kernel, not a fallback
+    assert _named(hlo, name)
 
 
 def test_pair_count_compiles(spec):
     ev = spec((ROWS,), jnp.int32)
-    _compile(pair_count_pallas, ev, ev, spec((ROWS,), jnp.float32),
+    _compile(pair_count_pallas, "pair_count_pallas", ev, ev,
+             spec((ROWS,), jnp.float32),
              num_src=ACTIVITIES, num_dst=ACTIVITIES)
 
 
 @pytest.mark.parametrize("bins", [ACTIVITIES, ACTIVITIES * ACTIVITIES])
 def test_histogram_compiles(spec, bins):
-    _compile(histogram_pallas, spec((ROWS,), jnp.int32),
+    _compile(histogram_pallas, "histogram_pallas", spec((ROWS,), jnp.int32),
              spec((ROWS,), jnp.int32), num_bins=bins)
 
 
@@ -79,23 +94,26 @@ def test_histogram_compiles(spec, bins):
                                       ("min", jnp.float32),
                                       ("max", jnp.uint32)])
 def test_segment_reduce_compiles(spec, op, dtype):
-    _compile(segment_reduce_pallas, spec((ROWS,), dtype),
-             spec((ROWS,), jnp.int32), num_segments=CASES, op=op)
+    _compile(segment_reduce_pallas, "segment_reduce_pallas",
+             spec((ROWS,), dtype), spec((ROWS,), jnp.int32),
+             num_segments=CASES, op=op)
 
 
 def test_segmented_polyhash_compiles(spec):
-    _compile(segmented_polyhash_pallas, spec((ROWS,), jnp.uint32),
-             spec((ROWS,), jnp.bool_), spec((), jnp.uint32), base=1_000_003)
+    _compile(segmented_polyhash_pallas, "segmented_affine_pallas",
+             spec((ROWS,), jnp.uint32), spec((ROWS,), jnp.bool_),
+             spec((), jnp.uint32), base=1_000_003)
 
 
 def test_segmented_affine_compiles(spec):
     ev = spec((ROWS,), jnp.uint32)
-    _compile(segmented_affine_pallas, ev, ev, spec((ROWS,), jnp.bool_),
-             spec((), jnp.uint32))
+    _compile(segmented_affine_pallas, "segmented_affine_pallas", ev, ev,
+             spec((ROWS,), jnp.bool_), spec((), jnp.uint32))
 
 
 def test_segmented_sum_scan_compiles(spec):
-    _compile(segmented_sum_scan_pallas, spec((ROWS, ACTIVITIES), jnp.float32),
+    _compile(segmented_sum_scan_pallas, "segmented_sum_scan_pallas",
+             spec((ROWS, ACTIVITIES), jnp.float32),
              spec((ROWS,), jnp.bool_), spec((ACTIVITIES,), jnp.float32))
 
 
@@ -121,10 +139,13 @@ def test_kernels_compile_inside_shard_map(topo, spec):
     hlo = fn.lower(ev(jnp.uint32), ev(jnp.uint32), ev(jnp.bool_),
                    ev(jnp.int32)).compile().as_text()
     assert mesh.size == 4 and "tpu_custom_call" in hlo
+    assert _named(hlo, "segmented_affine_pallas")
+    assert _named(hlo, "segment_reduce_pallas")
 
 
 @pytest.mark.parametrize("nodes", [ACTIVITIES + 2, 300])
 @pytest.mark.parametrize("semiring", SEMIRINGS)
 def test_semiring_matmul_compiles(spec, semiring, nodes):
     m = spec((nodes, nodes), jnp.float32)
-    _compile(semiring_matmul_pallas, m, m, semiring=semiring)
+    _compile(semiring_matmul_pallas, "semiring_matmul_pallas", m, m,
+             semiring=semiring)
