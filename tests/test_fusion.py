@@ -261,6 +261,128 @@ def test_explain_and_profile(logset):
         ds.collect_many(["dfg", "dfg"])
 
 
+# ----------------------------------- one fused program per chunk dispatch
+@pytest.fixture(scope="module", params=[37, 97, 10_000])
+def rg_logset(request, tmp_path_factory):
+    frame, tables = synthetic.generate(num_cases=40, num_activities=A,
+                                       seed=31)
+    d = tmp_path_factory.mktemp(f"fused_rg{request.param}")
+    return _split_paths(frame, tables, d, case_cuts=[20],
+                        row_group_rows=request.param)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_fused_program_matches_per_member_loop(rg_logset, impl, monkeypatch):
+    """The single fused program runs the same member updates in the same
+    order as the per-member loop: a streaming profile and a collect_many
+    with the nested ``stats`` compose give the same bits either way."""
+    from repro.dataset.engines import clear_result_cache
+    from repro.query.statecache import state_cache
+
+    verbs = ("dfg", "stats", "variants", "performance_dfg")
+
+    def run():
+        clear_result_cache()
+        state_cache().clear()
+        ds = repro.open(rg_logset)
+        prof = ds.profile(engine="streaming")
+        many = ds.collect_many(verbs, engine="streaming")
+        return prof, many
+
+    with backend.use_backend(impl):
+        fused_prof, fused_many = run()
+        with monkeypatch.context() as m:
+            # every member update counts as plain Python: the loop path
+            m.setattr(engine, "_program", lambda update: None)
+            loop_prof, loop_many = run()
+    assert "fold.update.fused" in fused_prof.report.timings
+    assert "fold.update.fused" not in loop_prof.report.timings
+    assert loop_prof.report.timings["fold.update.dfg"][0] \
+        == loop_prof.report.groups_read
+    _assert_tree_equal(fused_prof.results, loop_prof.results, "profile")
+    _assert_tree_equal(fused_many.results, loop_many.results, "collect_many")
+
+
+def test_fused_update_is_shared_across_makes():
+    """compose_specs(...).make() builds a new compose on every collect; the
+    jitted program it dispatches must be the same one, or every collect
+    would trace and compile it again.  Nested composes (``stats``) are
+    stable members too."""
+    dims = engine.Dims(A, NC)
+    fused = engine.compose_specs(
+        {v: engine.kernel_spec(v) for v in ("dfg", "stats", "variants")})
+    k1, k2 = fused.make(dims), fused.make(dims)
+    assert k1.update is k2.update
+    assert isinstance(k1.update.program, jax.stages.Wrapped)
+    s1 = engine.kernel_spec("stats").make(dims)
+    s2 = engine.kernel_spec("stats").make(dims)
+    assert s1.update is s2.update
+    # a one-member compose dispatches the member's own update
+    solo = engine.compose_specs({"dfg": engine.kernel_spec("dfg")}).make(dims)
+    assert not hasattr(solo.update, "program")
+
+
+def test_fused_update_donates_state_and_carry(logset):
+    """The fused program reuses the buffers of the state and carry it is
+    given (a TPU host pays for every fresh output buffer); the chunk is
+    left alone."""
+    from repro.core.chunked import ChunkedEventFrame
+    from repro.core.dfg import dfg_kernel
+
+    _, frame, _ = logset
+    fused = engine.compose({"dfg": dfg_kernel(A),
+                            "sojourn_times": sojourn_times_kernel(A)})
+    chunk = next(iter(ChunkedEventFrame.from_frame(frame, 61)))
+    state, carry = fused.init()
+    out = fused.update(state, carry, chunk)
+    jax.block_until_ready(out)
+    assert all(x.is_deleted() for x in jax.tree.leaves((state, carry)))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(chunk))
+
+
+def test_second_profile_compiles_nothing(logset):
+    """With the result memo and the state cache cleared, a second profile
+    reruns every fold and finalize, and finds every program compiled."""
+    from repro.dataset.engines import clear_result_cache
+    from repro.query.statecache import state_cache
+
+    paths, _, _ = logset
+    reports = []
+    for _ in range(2):
+        clear_result_cache()
+        state_cache().clear()
+        reports.append(repro.open(paths).profile(engine="streaming").report)
+    assert reports[1].timings["fold.update.fused"][0] \
+        == reports[1].groups_read > 0
+    assert reports[1].compiles == {}
+
+
+def test_compose_with_a_plain_python_member_loops(logset):
+    """A member whose update is not jitted cannot join a fused program:
+    the composed kernel calls each member in turn, with its own span, and
+    gives the member's own results."""
+    from repro import obs
+    from repro.core.chunked import ChunkedEventFrame
+    from repro.core.dfg import dfg_kernel
+
+    _, frame, _ = logset
+    dk = dfg_kernel(A)
+    soj = sojourn_times_kernel(A)
+    plain = engine.ChunkKernel(
+        "plain_dfg", dk.init, lambda s, c, chunk: dk.update(s, c, chunk),
+        dk.merge, dk.finalize, columns=dk.columns)
+    fused = engine.compose({"dfg": plain, "sojourn_times": soj})
+    assert not hasattr(fused.update, "program")
+    chunks = list(ChunkedEventFrame.from_frame(frame, 61))
+    with obs.record() as rec:
+        got = engine.run_streaming(fused, chunks)
+    assert rec.timings["fold.update.dfg"][0] == len(chunks)
+    assert "fold.update.fused" not in rec.timings
+    _assert_tree_equal(got["dfg"], engine.run_streaming(dk, chunks), "dfg")
+    _assert_tree_equal(got["sojourn_times"],
+                       engine.run_streaming(soj, chunks), "sojourn")
+
+
 # -------------------------------- S2: prefetcher + ReaderPool under threads
 def test_prefetch_on_off_bitwise_identical(logset):
     """The double buffer changes wall clock, never bytes or results: the
