@@ -356,14 +356,18 @@ def shift_segments(arr: jax.Array, offset: int, fill=0) -> jax.Array:
     """Relabel a per-segment state vector by ``offset`` slots (how a merge
     maps ``b``'s local segment ids into the concatenation's numbering).
     Entries shifted past capacity drop — matching the sequential fold's
-    out-of-range scatter drop."""
+    out-of-range scatter drop.  The offset is traced, so merges at any
+    offset share one program per state shape."""
     if offset <= 0:
         return arr
+    return _shift_segments(arr, min(int(offset), arr.shape[0]), fill)
+
+
+@jax.jit
+def _shift_segments(arr, offset, fill):
     cap = arr.shape[0]
-    out = jnp.full_like(arr, fill)
-    if offset < cap:
-        out = out.at[offset:].set(arr[:cap - offset])
-    return out
+    padded = jnp.concatenate([jnp.full_like(arr, fill), arr])
+    return jax.lax.dynamic_slice_in_dim(padded, cap - offset, cap)
 
 
 def _compose4(a: tuple, b: tuple) -> tuple:

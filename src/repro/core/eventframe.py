@@ -135,13 +135,19 @@ class EventFrame:
 
     # --------------------------------------------------------- construct
     @staticmethod
-    def from_numpy(columns: Mapping[str, np.ndarray], valid: Mapping[str, np.ndarray] | None = None) -> "EventFrame":
+    def from_numpy(columns: Mapping[str, np.ndarray],
+                   valid: Mapping[str, np.ndarray] | None = None,
+                   device: jax.Device | None = None) -> "EventFrame":
+        """Put host columns on ``device`` (committed there), or on the
+        default device when it is None."""
         lens = {k: len(v) for k, v in columns.items()}
         if len(set(lens.values())) > 1:
             raise ValueError(f"ragged columns: {lens}")
+        put = jnp.asarray if device is None else \
+            (lambda v: jax.device_put(v, device))
         return EventFrame(
-            {k: jnp.asarray(v) for k, v in columns.items()},
-            {k: jnp.asarray(v) for k, v in (valid or {}).items()},
+            {k: put(v) for k, v in columns.items()},
+            {k: put(v) for k, v in (valid or {}).items()},
         )
 
     def to_numpy(self) -> dict[str, np.ndarray]:

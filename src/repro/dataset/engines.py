@@ -18,12 +18,13 @@ differ only in how they cut the stream into units —
   order-sensitive float accumulators: ``sojourn_times`` /
   ``performance_dfg`` / ``stats``) and plans with case-level predicates
   keep the sequential carry-threaded scan — same results, no caching.
-* **sharded** — one unit per shard: verbs with a hand-written
-  distributed lowering (``KernelSpec.sharded_state``) keep the
-  ppermute-halo + psum drivers; every *other* mergeable verb shards as a
-  literal merge-tree instance (``distributed.query.merge_tree_sharded``
-  — contiguous spans of the pruned stream folded independently, states
-  merged, finalized once).
+* **sharded** — one unit per chip: every mergeable verb shards as a
+  merge tree over chips (``distributed.query.merge_tree_sharded``) —
+  the pruned schedule cut into one contiguous span of row groups per
+  device, each span decoded onto and folded on its own chip, the span
+  states merged on one chip and finalized once.  The hand-written
+  ppermute-halo + psum lowerings (``KernelSpec.sharded_state``) stay
+  callable but are off this path.
 
 Whole :class:`CollectResult`/:class:`CollectManyResult` values are also
 memoized per process, keyed by the plan fingerprint and each file's
@@ -55,8 +56,8 @@ time, never correctness.
 one :func:`~repro.core.engine.compose_specs` fused spec and drives the
 chosen engine ONCE: one pruned scan (columns = the union of the member
 requirements, ``mask_exact`` = their conjunction), one eager load, or
-one sharded pass over the distinct distributed states — each verb's
-result bitwise equal to its separate ``collect`` call.
+one sharded pass over the chips' spans — each verb's result bitwise
+equal to its separate ``collect`` call.
 """
 from __future__ import annotations
 
@@ -354,89 +355,41 @@ def eager_frame(dataset) -> EventFrame:
     return frame
 
 
-def _mesh(num_shards):
-    import jax
-
-    devs = jax.devices()
-    num_shards = len(devs) if num_shards is None else int(num_shards)
-    return jax.sharding.Mesh(np.array(devs[:num_shards]), ("data",))
-
-
-def _num_shards(num_shards) -> int:
-    if num_shards is not None:
-        return max(int(num_shards), 1)
-    import jax
-
-    return len(jax.devices())
-
-
 def _sharded(dataset, spec: _engine.KernelSpec, dims, num_shards, **kwargs):
-    from repro.distributed.query import query_sharded_multi
+    from repro.distributed.query import merge_tree_sharded
 
     if not dataset.is_files:
         raise ValueError("engine='sharded' needs a file-backed dataset")
-    if spec.sharded_state is None:
-        # no bespoke distributed state — but a mergeable kernel shards as
-        # a merge-tree instance over contiguous spans of the pruned stream
-        from repro.distributed.query import merge_tree_sharded
-
-        kernel = spec.make(dims, **kwargs)
-        if not _engine.mergeable(kernel):
-            raise ValueError(
-                f"verb {spec.name!r} has no exact distributed lowering "
-                f"(order-sensitive state, no stitch); use "
-                f"engine='streaming' or 'eager'")
-        return merge_tree_sharded(dataset.plan(columns=spec.columns),
-                                  kernel, _num_shards(num_shards))
-    # same projection/column validation as the other engines (the driver
-    # re-projects the scan to its own (activity, case) columns anyway)
-    plan = dataset.plan(columns=spec.columns)
-    out, report = query_sharded_multi(plan, (spec.sharded_state,),
-                                      dims.num_activities, _mesh(num_shards),
-                                      method=kwargs.get("method", "auto"),
-                                      num_cases=dims.num_cases)
-    return spec.from_sharded(out[spec.sharded_state], **kwargs), report
+    kernel = spec.make(dims, **kwargs)
+    if not _engine.mergeable(kernel):
+        raise ValueError(
+            f"verb {spec.name!r} has no exact distributed lowering "
+            f"(order-sensitive state, no stitch); use "
+            f"engine='streaming' or 'eager'")
+    return merge_tree_sharded(dataset.plan(columns=spec.columns), kernel,
+                              num_shards)
 
 
 def _sharded_many(dataset, specs: Mapping[str, _engine.KernelSpec],
                   fused: _engine.KernelSpec, dims, num_shards,
                   verb_kwargs: Mapping[str, dict], common: dict):
-    from repro.distributed.query import query_sharded_multi
+    from repro.distributed.query import merge_tree_sharded
 
     if not dataset.is_files:
         raise ValueError("engine='sharded' needs a file-backed dataset")
-    if fused.sharded_state is None:
-        # same merge-tree fallback as single-verb collects: a fused kernel
-        # stitches iff every member does
-        from repro.distributed.query import merge_tree_sharded
-
-        kernel = fused.make(dims, verb_kwargs=dict(verb_kwargs), **common)
-        if not _engine.mergeable(kernel):
-            bad = sorted(v for v, s in specs.items()
-                         if s.sharded_state is None and
-                         not _engine.mergeable(s.make(dims, **{
-                             **common, **dict(verb_kwargs.get(v, {}))})))
-            raise ValueError(
-                f"fused collection has no exact distributed lowering: verbs "
-                f"{bad} (order-sensitive state, no stitch); drop them or "
-                f"use engine='streaming' or 'eager'")
-        results, report = merge_tree_sharded(
-            dataset.plan(columns=fused.columns), kernel,
-            _num_shards(num_shards))
-        return dict(results), report
-    # verbs sharing a distributed state (dfg + alpha, discovery +
-    # heuristics) dedupe: each distinct state is mined once from the one
-    # gathered stream, then every verb finalizes host-side from its state
-    states = tuple(dict.fromkeys(s.sharded_state for s in specs.values()))
-    plan = dataset.plan(columns=fused.columns)
-    out, report = query_sharded_multi(plan, states, dims.num_activities,
-                                      _mesh(num_shards),
-                                      method=common.get("method", "auto"),
-                                      num_cases=dims.num_cases)
-    results = {v: s.from_sharded(out[s.sharded_state],
-                                 **{**common, **dict(verb_kwargs.get(v, {}))})
-               for v, s in specs.items()}
-    return results, report
+    # a fused kernel stitches iff every member does
+    kernel = fused.make(dims, verb_kwargs=dict(verb_kwargs), **common)
+    if not _engine.mergeable(kernel):
+        bad = sorted(v for v, s in specs.items()
+                     if not _engine.mergeable(s.make(dims, **{
+                         **common, **dict(verb_kwargs.get(v, {}))})))
+        raise ValueError(
+            f"fused collection has no exact distributed lowering: verbs "
+            f"{bad} (order-sensitive state, no stitch); drop them or "
+            f"use engine='streaming' or 'eager'")
+    results, report = merge_tree_sharded(
+        dataset.plan(columns=fused.columns), kernel, num_shards)
+    return dict(results), report
 
 
 # ------------------------------------------------------------- front door
@@ -548,8 +501,7 @@ def collect_many(dataset, verbs: Iterable[str], *, engine: str = "auto",
     spec — one kernel, one scan whose projection is the union of the
     member column requirements — and dispatch like any other verb:
     ``engine="auto"`` applies the calibrated cost model to the fused
-    spec, ``"sharded"`` mines each distinct distributed state once from
-    one gathered stream.  Every registered verb is pruning-exact
+    spec, ``"sharded"`` folds the fused kernel once over the chips' spans.  Every registered verb is pruning-exact
     (``variants`` replays skipped groups from header sketches), so the
     fused scan always skips refuted groups whatever the member mix.
 

@@ -1,5 +1,11 @@
 """Distributed pruned scans: surviving row groups sharded over devices.
 
+The sharded engine's path is :func:`merge_tree_sharded`: one contiguous
+span of row groups per chip, each span decoded onto and folded on its own
+chip, the span states merged on one chip (``core.engine.merge_tree``).
+The halo lowerings below (``query_sharded_multi`` and its ``_gather``)
+are the older schedule, kept callable but off that path.
+
 The query layer's pruned stream (``repro.query.exec.pruned_source``)
 collapses zone-map-refuted row groups to O(segments) ghost rows; this
 module concatenates that stream, splits it into equal contiguous shards
@@ -28,19 +34,24 @@ code path and are bitwise equal state-for-state.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import engine
 from repro.core.dfg import DFG, dfg_kernel
 from repro.core.discovery import DiscoveryState, discovery_kernel
 from repro.core.eventframe import ACTIVITY, CASE
 from repro.core.polyhash import BASE1, BASE2, SK_ADD1, SK_ADD2, SK_MUL1, \
     SK_MUL2
-from repro.query.exec import pruned_source
+from repro.query.exec import (pruned_source, span_chunks, span_schedules,
+                              span_workers)
 from repro.query.plan import MultiPlan, Plan
 
 from .dfg import fix_trailing_end, run_sharded_composed
@@ -230,36 +241,73 @@ def query_sharded_multi(plan: "Plan | MultiPlan", states, num_activities: int,
     return result, report
 
 
-def merge_tree_sharded(plan: "Plan | MultiPlan", kernel, num_shards: int,
-                       *, prune: bool = True, prefetch: int | None = None):
-    """Shard a pruned scan as a merge tree over the group-state algebra.
+def _fold_span(kernel, index: int, pieces, prefetch: int, device, start,
+               rec) -> engine.GroupState:
+    """Span ``index``'s fresh fold on ``device``: its groups decoded by its
+    read-ahead worker (``start``) and folded with the one-chip
+    ``fold_group``, under the collect's ``obs`` record."""
+    with obs.bind(rec), jax.default_device(device), \
+            obs.span(f"shard.span.{index}"):
+        chunks = span_chunks(pieces, prefetch, device, start)
+        try:
+            return engine.fold_group(kernel, chunks)
+        finally:
+            chunks.close()          # stops the read-ahead on an error
 
-    The classic drivers above shard with a ppermute halo + one ``psum`` —
-    a lowering only states with hand-written distributed kernels have.
-    With mergeable group states (``core.engine.GroupState``) the psum *is*
-    a merge-tree instance: split the pruned chunk stream into
-    ``num_shards`` contiguous spans, fold each span fresh (exactly what a
-    shard's local pass computes), then ``merge_tree`` the span states and
-    finalize once.  Every kernel with a ``stitch`` gains a sharded
-    schedule this way — case sizes, durations, activity counts,
-    eventually-follows — with no bespoke halo code, and the result stays
-    bitwise equal to the streamed fold (the merge reconstructs it).
+
+def _to_home(gs: engine.GroupState, device) -> engine.GroupState:
+    return dataclasses.replace(gs, state=jax.device_put(gs.state, device),
+                               carry=jax.device_put(gs.carry, device))
+
+
+def merge_tree_sharded(plan: "Plan | MultiPlan", kernel,
+                       num_shards: int | None = None, *, prune: bool = True,
+                       prefetch: int | None = None):
+    """Shard a pruned scan as a merge tree over chips.
+
+    The pruned schedule of row groups is cut into ``num_shards`` contiguous
+    spans (default: one per device).  Span *i* is read ahead, decoded, put
+    on device *i* and folded there by its own worker
+    (``query.exec.span_workers``), with the same ``fold_group`` and
+    per-chunk programs the one-chip path runs;
+    the span states then move to the first device, ``merge_tree`` combines
+    them with the kernels' own stitches, and ``finalize_group`` runs once.
+    No column of the log is concatenated on the host.  The merge is
+    bitwise-associative, so the result equals the streamed fold, bitwise,
+    for every kernel with a ``stitch``.
+
+    Spans and counters on the report: ``shard.span.<i>`` (span *i*'s
+    worker), ``shard.wait`` (the caller waiting for the span states),
+    ``shard.merge`` (moving them plus ``merge_tree``) and ``chips_used``.
 
     Returns ``(result, ScanReport)``.
     """
     if not engine.mergeable(kernel):
         raise ValueError(f"kernel {kernel.name!r} defines no stitch — no "
                          f"merge-tree sharding (and no distributed state)")
-    src, report = pruned_source(
-        plan, prune=prune, mask_exact=getattr(kernel, "mask_exact", True),
-        sketch=getattr(kernel, "ghost_sketch", False), prefetch=prefetch)
-    chunks = [c for c in src if c.nrows]
-    n = max(int(num_shards), 1)
-    bounds = np.linspace(0, len(chunks), n + 1).round().astype(int)
-    states = [engine.fold_group(kernel, chunks[lo:hi])
-              for lo, hi in zip(bounds[:-1], bounds[1:]) if hi > lo]
-    merged = engine.merge_tree(kernel, states)
-    return engine.finalize_group(kernel, merged), report
+    devs = jax.devices()
+    n = len(devs) if num_shards is None else max(int(num_shards), 1)
+    with obs.record() as rec, obs.span("scan"):
+        spans, report = span_schedules(
+            plan, n, prune=prune,
+            mask_exact=getattr(kernel, "mask_exact", True),
+            sketch=getattr(kernel, "ghost_sketch", False), prefetch=prefetch)
+        homes = [devs[i % len(devs)] for i in range(len(spans))]
+        futures = [
+            fold.submit(functools.partial(_fold_span, kernel, i, pieces,
+                                          report.prefetch, homes[i],
+                                          read.submit, rec))
+            for i, (pieces, (fold, read))
+            in enumerate(zip(spans, span_workers(len(spans))))]
+        with obs.span("shard.wait"):
+            states = [f.result() for f in futures]
+        with obs.span("shard.merge"):
+            merged = engine.merge_tree(
+                kernel, [_to_home(gs, devs[0]) for gs in states])
+        result = engine.finalize_group(kernel, merged)
+    obs.add(report, rec)
+    report.chips_used = len(set(homes))
+    return result, report
 
 
 def query_sharded_dfg(plan: "Plan | MultiPlan", num_activities: int, mesh,

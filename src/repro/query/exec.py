@@ -58,10 +58,13 @@ prefetcher on or off.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import os
 import queue
 import threading
+from concurrent.futures import Future
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -90,6 +93,7 @@ class ScanReport:
     columns: tuple
     pruned: bool
     prefetch: int = 0           # read-ahead depth the scan ran with
+    chips_used: int = 0         # sharded scans: devices that folded a span
     groups_total: int = 0
     groups_read: int = 0
     groups_skipped: int = 0
@@ -178,10 +182,14 @@ def prefetch_depth(prefetch: int | None = None) -> int:
     return max(int(prefetch), 0)
 
 
-def _read_ahead(reader: EDFReader, schedule, read_columns, depth: int):
+def _read_ahead(reader: EDFReader, schedule, read_columns, depth: int,
+                device=None, start=None):
     """Yield ``(item, frame | None)`` pairs in schedule order, fetching and
     decoding up to ``depth`` read groups ahead on a daemon thread (the
-    double buffer: group *g+1* decompresses while the kernel runs on *g*).
+    double buffer: group *g+1* decompresses while the kernel runs on *g*),
+    which puts them on ``device`` (None: the default device).  ``start``
+    runs the worker (a span's read-ahead :class:`_Worker`); by default it
+    gets a thread of its own.
     Ghost items pass through with ``frame=None`` — their synthesis is
     order-dependent and stays on the consumer.  The worker serves the
     consumer's bound ``obs`` record; the consumer's waits are the
@@ -211,15 +219,18 @@ def _read_ahead(reader: EDFReader, schedule, read_columns, depth: int):
                     elif stop.is_set():
                         return
                     else:
-                        out = (item, reader.read_group(item.index,
-                                                       read_columns))
+                        out = (item, reader.read_group(
+                            item.index, read_columns, device))
                     if not _put(out):
                         return
             except BaseException as exc:  # noqa: BLE001 — re-raised at consumer
                 _put(exc)
 
-    t = threading.Thread(target=worker, daemon=True, name="repro-prefetch")
-    t.start()
+    if start is None:
+        threading.Thread(target=worker, daemon=True,
+                         name="repro-prefetch").start()
+    else:
+        start(worker)
     try:
         for item in schedule:
             with obs.span("scan.wait", group=getattr(item, "index", -1)):
@@ -236,8 +247,12 @@ def _read_ahead(reader: EDFReader, schedule, read_columns, depth: int):
                 break
 
 
-def _ghost_chunk(item: GhostItem, chunk_columns, reader: EDFReader
-                 ) -> EventFrame:
+def _to_device(x: np.ndarray, device=None):
+    return jnp.asarray(x) if device is None else jax.device_put(x, device)
+
+
+def _ghost_chunk(item: GhostItem, chunk_columns, reader: EDFReader,
+                 device=None) -> EventFrame:
     """One all-masked row per case segment of a skipped run (padded to a
     power of two so ghost shapes retrace the kernel O(log) times)."""
     d = max(int(item.segments), 1)
@@ -267,36 +282,41 @@ def _ghost_chunk(item: GhostItem, chunk_columns, reader: EDFReader
         # identity maps on padding — sketch-consuming kernels (variants)
         # fold these instead of hashing the unread rows
         cols.update(sketch_columns(item.sketch, d, m))
-    frame = EventFrame.from_numpy(cols, valid)
-    return EventFrame(frame.columns, frame.valid, jnp.zeros(m, bool))
+    frame = EventFrame.from_numpy(cols, valid, device)
+    return EventFrame(frame.columns, frame.valid,
+                      _to_device(np.zeros(m, bool), device))
 
 
 def _iter_chunks(physical: PhysicalPlan, schedule, keeps: dict,
-                 chunk_columns, read_columns, prefetch: int | None = None):
+                 chunk_columns, read_columns, prefetch: int | None = None, *,
+                 last_seg: int = -1, prev_case=None, device=None,
+                 start=None):
     """Yield the pruned chunk stream: read groups with residual masks,
     ghost chunks for skipped runs.  Tracks global segment numbering
     sequentially (read groups from their case column, ghost runs from
-    metadata), so case-level keep masks broadcast to the right rows.
+    metadata), so case-level keep masks broadcast to the right rows;
+    ``last_seg``/``prev_case`` are the numbering before ``schedule``'s
+    first item (a span that starts inside a file).
     ``prefetch`` groups are fetched+decoded ahead on a background thread
     (:func:`prefetch_depth` resolves ``None`` from the environment); the
     masking below consumes them strictly in schedule order, so the stream
-    is bitwise identical with read-ahead on or off."""
+    is bitwise identical with read-ahead on or off.  Chunks land on
+    ``device`` (None: the default device)."""
     reader = physical.reader
     steps = physical.steps
     depth = prefetch_depth(prefetch)
     if depth > 0:
-        pairs = _read_ahead(reader, schedule, read_columns, depth)
+        pairs = _read_ahead(reader, schedule, read_columns, depth, device,
+                            start)
     else:
         pairs = ((item, None) for item in schedule)
     # global segment ids are only materialized when a keep mask needs the
     # broadcast; ghost continuation needs just the previous case id
     track_segs = any(getattr(item, "case_steps", ()) for item in schedule)
-    last_seg = -1
-    prev_case = None
     try:
         yield from _masked_chunks(pairs, reader, steps, keeps, chunk_columns,
                                   read_columns, track_segs, last_seg,
-                                  prev_case)
+                                  prev_case, device)
     finally:
         close = getattr(pairs, "close", None)
         if close is not None:
@@ -304,16 +324,16 @@ def _iter_chunks(physical: PhysicalPlan, schedule, keeps: dict,
 
 
 def _masked_chunks(pairs, reader, steps, keeps, chunk_columns, read_columns,
-                   track_segs, last_seg, prev_case):
+                   track_segs, last_seg, prev_case, device=None):
     for item, frame in pairs:
         if isinstance(item, GhostItem):
             cont = prev_case is not None and item.first_case == prev_case
-            yield _ghost_chunk(item, chunk_columns, reader)
+            yield _ghost_chunk(item, chunk_columns, reader, device)
             last_seg += int(item.segments) - (1 if cont else 0)
             prev_case = item.tail["values"][CASE]
             continue
         if frame is None:
-            frame = reader.read_group(item.index, read_columns)
+            frame = reader.read_group(item.index, read_columns, device)
         with obs.span("scan.mask", group=item.index):
             mask = np.ones(frame.nrows, bool)
             for pos in item.residual:
@@ -331,7 +351,8 @@ def _masked_chunks(pairs, reader, steps, keeps, chunk_columns, read_columns,
                     last_seg = int(seg[-1])
                 prev_case = case[-1]
             sel = frame.select(chunk_columns)
-            chunk = EventFrame(sel.columns, sel.valid, jnp.asarray(mask))
+            chunk = EventFrame(sel.columns, sel.valid,
+                               _to_device(mask, device))
         yield chunk
 
 
@@ -847,6 +868,131 @@ def pruned_source(plan: "Plan | MultiPlan", *, prune: bool = True,
         plan = MultiPlan((plan.path,), plan.steps, plan.projection)
     return multi_pruned_source(plan, prune=prune, mask_exact=mask_exact,
                                sketch=sketch, prefetch=prefetch)
+
+
+# ------------------------------------------------------------ span sources
+class _Worker:
+    """One daemon thread that runs submitted calls in order, started once
+    and kept for the life of the process."""
+
+    def __init__(self, name: str):
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        threading.Thread(target=self._run, daemon=True, name=name).start()
+
+    def _run(self):
+        while True:
+            fn, fut = self._q.get()
+            if fut.set_running_or_notify_cancel():
+                try:
+                    fut.set_result(fn())
+                except BaseException as exc:  # noqa: BLE001 — caller re-raises
+                    fut.set_exception(exc)
+
+    def submit(self, fn) -> Future:
+        fut: Future = Future()
+        self._q.put((fn, fut))
+        return fut
+
+
+_SPAN_WORKERS: list = []
+_SPAN_WORKERS_LOCK = threading.Lock()
+
+
+def span_workers(n: int) -> list:
+    """``(fold, read_ahead)`` worker pairs of spans ``0..n-1``, started on
+    first use and reused by every later sharded scan.  Only span *i*'s fold
+    submits to span *i*'s read-ahead worker, so scans queued on the same
+    workers run one after another and never wait on each other."""
+    with _SPAN_WORKERS_LOCK:
+        while len(_SPAN_WORKERS) < n:
+            i = len(_SPAN_WORKERS)
+            _SPAN_WORKERS.append((_Worker(f"repro-span-{i}"),
+                                  _Worker(f"repro-span-{i}-read")))
+        return _SPAN_WORKERS[:n]
+
+
+@dataclasses.dataclass(frozen=True)
+class SpanPiece:
+    """The part of one span that lies in one file: its schedule items, the
+    file's case keep masks, and the segment numbering before the first
+    item (what :func:`_iter_chunks` would have tracked up to there)."""
+
+    physical: PhysicalPlan
+    items: tuple
+    keeps: dict
+    last_seg: int
+    prev_case: object
+
+
+def _numbering_after(ph: PhysicalPlan, item, last_seg: int, prev_case):
+    """The ``(last_seg, prev_case)`` that :func:`_masked_chunks` holds after
+    ``item``, from header metadata alone."""
+    if isinstance(item, GhostItem):
+        cont = prev_case is not None and item.first_case == prev_case
+        return (last_seg + int(item.segments) - int(cont),
+                item.tail["values"][CASE])
+    meta = ph.metas[item.index]
+    new0 = prev_case is None or meta["zones"][CASE]["min"] != prev_case
+    return (last_seg + int(new0) + int(meta["segments"]) - 1,
+            meta["tail"]["values"][CASE])
+
+
+def span_schedules(plan: "Plan | MultiPlan", num_spans: int, *,
+                   prune: bool = True, mask_exact: bool = True,
+                   sketch: bool = False, prefetch: int | None = None):
+    """Compile a plan and cut its pruned schedule into contiguous spans.
+
+    The schedule is the one :func:`pruned_source` streams (case-level
+    predicates resolve their keeps first, in a phase-one pass); it is cut
+    into at most ``num_spans`` spans with as equal a number of read groups
+    as possible.  Ghost items stay where they fall.  Each span is a tuple
+    of :class:`SpanPiece`, one per file it touches; :func:`span_chunks`
+    streams it.  Returns ``(spans, report)``; empty spans are dropped.
+    """
+    if isinstance(plan, Plan):
+        plan = MultiPlan((plan.path,), plan.steps, plan.projection)
+    physicals, reports, offsets, keeps = _multi_compile(plan, prune, prefetch)
+    schedules, locals_ = _multi_schedules(physicals, reports, offsets, keeps,
+                                          ghosts=mask_exact,
+                                          skippable=mask_exact, sketch=sketch)
+    depth = prefetch_depth(prefetch)
+    for rep in reports:
+        rep.prefetch = depth
+    entries = []        # (file, item, numbering before the item)
+    for fi, (ph, sched) in enumerate(zip(physicals, schedules)):
+        track = any(getattr(item, "case_steps", ()) for item in sched)
+        numbering = (-1, None)
+        for item in sched:
+            entries.append((fi, item, numbering))
+            if track:
+                numbering = _numbering_after(ph, item, *numbering)
+    reads = [k for k, e in enumerate(entries) if isinstance(e[1], ReadItem)]
+    bounds = np.linspace(0, len(reads), max(int(num_spans), 1) + 1)
+    cuts = [0] + [reads[b] if b < len(reads) else len(entries)
+                  for b in bounds[1:-1].round().astype(int)] + [len(entries)]
+    spans = []
+    for lo, hi in zip(cuts[:-1], cuts[1:]):
+        pieces = []
+        for fi, run in itertools.groupby(entries[lo:hi], key=lambda e: e[0]):
+            run = list(run)
+            pieces.append(SpanPiece(physicals[fi], tuple(e[1] for e in run),
+                                    locals_[fi], *run[0][2]))
+        if pieces:
+            spans.append(tuple(pieces))
+    return spans, merge_reports(reports)
+
+
+def span_chunks(pieces, prefetch: int | None = None, device=None,
+                start=None):
+    """The pruned chunk stream of one span on ``device``: its groups read
+    ahead by ``start`` (a span's read-ahead worker), then masked and ghosted
+    exactly as the whole stream would be there."""
+    for p in pieces:
+        ph = p.physical
+        yield from _iter_chunks(ph, p.items, p.keeps, ph.chunk_columns,
+                                ph.read_columns, prefetch,
+                                last_seg=p.last_seg, prev_case=p.prev_case,
+                                device=device, start=start)
 
 
 def execute(plan: "Plan | MultiPlan", mine: engine.ChunkKernel, *,

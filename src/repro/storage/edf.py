@@ -540,10 +540,10 @@ def _fetch_group_v2(f, base: int, header: dict, group: dict, want
     return fetched
 
 
-def _decode_group_v2(fetched: list[tuple], gn: int,
-                     index: int = -1) -> EventFrame:
+def _decode_group_v2(fetched: list[tuple], gn: int, index: int = -1,
+                     device=None) -> EventFrame:
     """Decompress + deserialize fetched extents (no file handle needed),
-    then put the columns on the device."""
+    then put the columns on ``device`` (None: the default device)."""
     cols: dict[str, np.ndarray] = {}
     valid: dict[str, np.ndarray] = {}
     with obs.span("edf.decode", group=index):
@@ -559,7 +559,7 @@ def _decode_group_v2(fetched: list[tuple], gn: int,
     with obs.span("edf.put", group=index):
         obs.put(sum(a.nbytes for a in cols.values())
                 + sum(a.nbytes for a in valid.values()))
-        return EventFrame.from_numpy(cols, valid)
+        return EventFrame.from_numpy(cols, valid, device)
 
 
 def _read_group_v2(f, base: int, header: dict, group: dict, want):
@@ -802,13 +802,19 @@ class EDFReader:
     def group_nrows(self, index: int) -> int:
         return self._groups()[index]["nrows"]
 
-    def read_group(self, index: int, columns: Iterable[str] | None = None
-                   ) -> EventFrame:
+    def read_group(self, index: int, columns: Iterable[str] | None = None,
+                   device=None) -> EventFrame:
+        """One row group, put on ``device`` (None: the default device)."""
         if self.version == 1:
             if index != 0:
                 raise IndexError("EDFV0001 has a single row group")
             self._check_sig()           # v1 re-opens per read: same guard
-            return _read_v1(self.path, columns)[0]
+            frame = _read_v1(self.path, columns)[0]
+            if device is not None:
+                import jax
+
+                frame = jax.device_put(frame, device)
+            return frame
         group = self.header["groups"][index]
         want = set(columns) if columns is not None else None
         # one handle serves every plan over this file (ReaderPool); its
@@ -819,7 +825,7 @@ class EDFReader:
         with obs.span("edf.fetch", group=index), self._io_lock:
             fetched = _fetch_group_v2(self._fh(), self.base, self.header,
                                       group, want)
-        return _decode_group_v2(fetched, group["nrows"], index)
+        return _decode_group_v2(fetched, group["nrows"], index, device)
 
     def group_meta(self, index: int) -> dict:
         """``{"nrows", "zones", "segments"?, "tail"?}`` for one row group."""

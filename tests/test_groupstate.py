@@ -179,3 +179,26 @@ def test_states_straddle_group_and_file_boundaries(impl, tmp_path, log24):
             ref = engine.finalize_group(
                 kernel, engine.fold_group(kernel, [frame]))
             assert eq(ref, got), name
+
+
+def test_variants_merges_at_any_offset_share_one_program(log24):
+    """``shift_segments`` takes a traced offset: ``variants`` merges at
+    three different offsets compile its program once, and the later
+    merges compile nothing."""
+    from repro import obs
+
+    # a capacity no other test folds at, so the first merge compiles
+    kernel = engine.kernel_spec("variants").make(engine.Dims(5, 29))
+    n = log24.nrows
+    whole = engine.finalize_group(kernel, engine.fold_group(kernel, [log24]))
+    offsets, compiles = [], []
+    for cut in (n // 4, n // 2, 3 * n // 4):
+        a, b = _fold_slices(kernel, log24, [(0, cut), (cut, n)])
+        with obs.record() as rec:
+            merged = engine.merge_tree(kernel, [a, b])
+        offsets.append(a.segments)
+        compiles.append(dict(rec.compiles))
+        assert eq(engine.finalize_group(kernel, merged), whole), cut
+    assert len(set(offsets)) == 3, offsets
+    assert compiles[0].get("jit(_shift_segments)") == 1, compiles[0]
+    assert compiles[1:] == [{}, {}], compiles

@@ -3,7 +3,9 @@ nesting, the read-ahead worker's spans on its scan's report, the spans
 and syncs of a streaming ``profile``, their sums across reports, compile
 counts by program, and the spans in a ``jax.profiler`` trace."""
 import glob
+import json
 import os
+import subprocess
 import sys
 import threading
 import time
@@ -154,6 +156,40 @@ def test_grouped_collect_reports_fold_merge_and_cache(log_path):
     assert rep.timings["cache.lookup"][0] == 2 * groups     # get, then put
     # fold_group pulls case, activity and validity of each group
     assert rep.host_syncs >= 3 * groups
+
+
+def test_sharded_collect_reports_its_spans(log_path):
+    """A four-chip sharded collect (virtual devices in a subprocess): one
+    ``shard.span.<i>`` a span, the wait, the merge with ``fold.merge``
+    inside it, ``chips_used``, and one decode a group read."""
+    code = f"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import repro
+rep = repro.open({log_path!r}).collect("dfg", engine="sharded").report
+print("RESULT " + json.dumps({{"timings": rep.timings,
+                              "chips_used": rep.chips_used,
+                              "groups_read": rep.groups_read}}))
+"""
+    env = dict(os.environ, PYTHONPATH=os.path.join(os.path.dirname(__file__),
+                                                   "..", "src"))
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    got = json.loads(res.stdout.split("RESULT ")[-1])
+    t = got["timings"]
+    assert got["chips_used"] == 4
+    assert sorted(k for k in t if k.startswith("shard.span.")) == [
+        f"shard.span.{i}" for i in range(4)]
+    for name in ("shard.span.0", "shard.span.3", "shard.wait",
+                 "shard.merge", "fold.merge", "fold.finalize", "scan"):
+        assert t[name][0] == 1, name
+    # fold.merge is the merge span's one child
+    _, total, own = t["shard.merge"]
+    assert total - own == pytest.approx(t["fold.merge"][1], abs=1e-6)
+    assert t["edf.decode"][0] == got["groups_read"] > 4
+    assert t["fold.group"][0] == 4
 
 
 def test_merge_reports_sums_the_counters():
