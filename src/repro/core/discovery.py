@@ -109,42 +109,83 @@ class AlphaModel:
         return len(self.places) + 2  # + source/sink
 
 
+_BROADCAST = 1 << 20        # pairs compared in one broadcast
+
+
+def _masks(rows: np.ndarray, words: int) -> np.ndarray:
+    """(k, n) bool -> (k, words) uint64: bit ``i % 64`` of word ``i // 64``
+    is set where ``rows[:, i]``."""
+    packed = np.zeros((rows.shape[0], 8 * words), np.uint8)
+    bits = np.packbits(rows, axis=1, bitorder="little")
+    packed[:, :bits.shape[1]] = bits
+    return packed.view("<u8")
+
+
+def _sets(masks: np.ndarray, n: int) -> list[frozenset[int]]:
+    """The activity set of each mask row (inverse of :func:`_masks`)."""
+    bits = np.unpackbits(np.ascontiguousarray(masks, "<u8").view(np.uint8),
+                         axis=1, bitorder="little")[:, :n]
+    return [frozenset(np.flatnonzero(row).tolist()) for row in bits]
+
+
+def _within(x: np.ndarray, y: np.ndarray) -> np.ndarray:
+    """Broadcast subset test: every set bit of ``x`` is set in ``y``."""
+    return ~(x & ~y).any(-1)
+
+
 def _maximal_pairs(causal: np.ndarray, choice: np.ndarray):
     """Classic alpha steps 3–4: the maximal (A, B) pairs.
 
     Any valid pair decomposes into valid singleton pairs (sub-pairs of a
-    valid pair are valid), so the closure of singleton pairs under
-    pairwise union reaches every element of X_L; Y_L is its maximal
+    valid pair are valid), so the closure of singleton pairs under union
+    with one singleton reaches every element of X_L; Y_L is its maximal
     antichain.  Host-side over the boolean footprint — the alphabet is
     small and fixed, the log size never enters here.
+
+    A pair is one row of bitmasks, A's words then B's, any alphabet size.
+    The union of a valid (A, B) with a valid singleton (a, b) is valid iff
+    the cross terms hold: A within ``causal[:, b]`` and in choice both
+    ways with ``a``, B within ``causal[a, :]`` and in choice both ways with
+    ``b``.  So each singleton carries one mask row ``need``, and a level of
+    the closure is one broadcast of the frontier against the singletons.
     """
-    a_n = causal.shape[0]
-    base = [(frozenset((a,)), frozenset((b,)))
-            for a in range(a_n) for b in range(a_n)
-            if causal[a, b] and choice[a, a] and choice[b, b]]
+    n = causal.shape[0]
+    free = np.diagonal(choice)
+    a_idx, b_idx = np.nonzero(causal & free[:, None] & free[None, :])
+    if not a_idx.size:
+        return ()
+    words = -(-n // 64)
+    one = _masks(np.eye(n, dtype=bool), words)
+    both = _masks(choice & choice.T, words)
+    base = np.concatenate([one[a_idx], one[b_idx]], axis=1)
+    need = np.concatenate([_masks(causal.T, words)[b_idx] & both[a_idx],
+                           _masks(causal, words)[a_idx] & both[b_idx]],
+                          axis=1)
 
-    def ok(aa, bb):
-        al, bl = sorted(aa), sorted(bb)
-        return (causal[np.ix_(al, bl)].all()
-                and choice[np.ix_(al, al)].all()
-                and choice[np.ix_(bl, bl)].all())
-
-    seen = set(base)
-    frontier = list(base)
-    while frontier:
+    step = max(1, _BROADCAST // len(base))
+    seen = frontier = base
+    while len(frontier):
         fresh = []
-        for a1, b1 in frontier:
-            for a2, b2 in base:
-                cand = (a1 | a2, b1 | b2)
-                if cand not in seen and ok(*cand):
-                    seen.add(cand)
-                    fresh.append(cand)
-        frontier = fresh
+        for lo in range(0, len(frontier), step):
+            f = frontier[lo:lo + step]
+            i, j = np.nonzero(_within(f[:, None], need[None]))
+            fresh.append(f[i] | base[j])
+        pool = np.concatenate([seen, *fresh])
+        _, first = np.unique(pool.view(np.dtype((np.void, 16 * words))),
+                             return_index=True)
+        first.sort()
+        frontier = pool[first[first >= len(seen)]]
+        seen = pool[first]
 
-    maximal = [p for p in seen
-               if not any(q != p and p[0] <= q[0] and p[1] <= q[1]
-                          for q in seen)]
-    return tuple(sorted(maximal, key=lambda p: (sorted(p[0]), sorted(p[1]))))
+    step = max(1, _BROADCAST // len(seen))
+    dominated = np.zeros(len(seen), bool)
+    for lo in range(0, len(seen), step):
+        sub = _within(seen[lo:lo + step, None], seen[None])
+        sub[np.arange(len(sub)), lo + np.arange(len(sub))] = False
+        dominated[lo:lo + step] = sub.any(1)
+    kept = seen[~dominated]
+    places = zip(_sets(kept[:, :words], n), _sets(kept[:, words:], n))
+    return tuple(sorted(places, key=lambda p: (sorted(p[0]), sorted(p[1]))))
 
 
 def discover_alpha(d: DFG, min_count: int = 1) -> AlphaModel:
@@ -153,7 +194,8 @@ def discover_alpha(d: DFG, min_count: int = 1) -> AlphaModel:
     fp = footprint(d, min_count)
     causal = obs.pull(fp.causal)
     choice = obs.pull(fp.choice)
-    places = _maximal_pairs(causal, choice)
+    with obs.span("alpha.places"):
+        places = _maximal_pairs(causal, choice)
     starts = frozenset(int(i) for i in np.nonzero(obs.pull(d.starts))[0])
     ends = frozenset(int(i) for i in np.nonzero(obs.pull(d.ends))[0])
     return AlphaModel(num_activities=d.num_activities, places=places,

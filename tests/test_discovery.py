@@ -234,3 +234,105 @@ def test_footprint_classes_partition():
     total = (causal.astype(int) + causal.T.astype(int)
              + parallel.astype(int) + choice.astype(int))
     np.testing.assert_array_equal(total, np.ones((a, a), int))
+
+
+# ------------------------------------------------ alpha place search
+def _closure_oracle(causal: np.ndarray, choice: np.ndarray):
+    """The place search as it was written over frozensets and ``np.ix_``
+    grids, kept verbatim as the oracle of the bitmask search."""
+    a_n = causal.shape[0]
+    base = [(frozenset((a,)), frozenset((b,)))
+            for a in range(a_n) for b in range(a_n)
+            if causal[a, b] and choice[a, a] and choice[b, b]]
+
+    def ok(aa, bb):
+        al, bl = sorted(aa), sorted(bb)
+        return (causal[np.ix_(al, bl)].all()
+                and choice[np.ix_(al, al)].all()
+                and choice[np.ix_(bl, bl)].all())
+
+    seen = set(base)
+    frontier = list(base)
+    while frontier:
+        fresh = []
+        for a1, b1 in frontier:
+            for a2, b2 in base:
+                cand = (a1 | a2, b1 | b2)
+                if cand not in seen and ok(*cand):
+                    seen.add(cand)
+                    fresh.append(cand)
+        frontier = fresh
+
+    maximal = [p for p in seen
+               if not any(q != p and p[0] <= q[0] and p[1] <= q[1]
+                          for q in seen)]
+    return tuple(sorted(maximal, key=lambda p: (sorted(p[0]), sorted(p[1]))))
+
+
+def _brute_force_places(causal: np.ndarray, choice: np.ndarray) -> set:
+    """Every subset pair, as ``alpha_reference`` enumerates them."""
+    n = causal.shape[0]
+    subsets = [frozenset(i for i in range(n) if m >> i & 1)
+               for m in range(1, 1 << n)]
+    cliques = [s for s in subsets if all(choice[x, y] for x in s for y in s)]
+    valid = [(aa, bb) for aa in cliques for bb in cliques
+             if all(causal[a, b] for a in aa for b in bb)]
+    return {p for p in valid
+            if not any(q != p and p[0] <= q[0] and p[1] <= q[1]
+                       for q in valid)}
+
+
+def _relations(direct: np.ndarray):
+    """(causal, choice) of a boolean directly-follows matrix."""
+    return direct & ~direct.T, ~direct & ~direct.T
+
+
+def _random_direct(n: int, density: float, seed: int) -> np.ndarray:
+    return np.random.default_rng(seed).random((n, n)) < density
+
+
+def _block_direct() -> np.ndarray:
+    """Activities 0-3 each directly followed by each of 4-7, and nothing
+    else: 15 x 15 = 225 valid pairs, one place."""
+    d = np.zeros((8, 8), bool)
+    d[:4, 4:] = True
+    return d
+
+
+def _l1_direct() -> np.ndarray:
+    """L1's process: the support of the program generator's transition
+    matrix for 26 activities, model seed 1 (every edge is observed in
+    L1's 7x10^6 events)."""
+    from repro.data.synthetic import random_process_model
+
+    _, trans, _ = random_process_model(26, 1)
+    return trans > 0
+
+
+FOOTPRINTS = {
+    **{f"random_{n}_{density}": (lambda n=n, density=density:
+                                 _random_direct(n, density, 100 + n))
+       for n in (4, 8, 12) for density in (0.15, 0.35)},
+    "block_4x4": _block_direct,
+    "l1": _l1_direct,
+    "random_70_sparse": lambda: _random_direct(70, 0.02, 70),
+    "no_free_activity": lambda: np.ones((5, 5), bool),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FOOTPRINTS))
+def test_maximal_pairs_match_the_closure(name):
+    """The bitmask search returns the frozenset closure's tuple exactly,
+    and brute force's places wherever brute force runs."""
+    causal, choice = _relations(FOOTPRINTS[name]())
+    got = discovery._maximal_pairs(causal, choice)
+    assert got == _closure_oracle(causal, choice)
+    assert all(type(i) is int for a, b in got for i in a | b)
+    if causal.shape[0] <= 8:
+        assert set(got) == _brute_force_places(causal, choice)
+    if name == "block_4x4":
+        assert got == ((frozenset(range(4)), frozenset(range(4, 8))),)
+    if name == "l1":
+        assert int(causal.sum()) == 144 and len(got) == 55
+    if name == "no_free_activity":
+        assert got == ()

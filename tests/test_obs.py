@@ -249,3 +249,12 @@ def test_spans_appear_in_a_profiler_trace(log_path, tmp_path):
         key = name[len(obs.PREFIX):]
         counts[key] = counts.get(key, 0) + 1
     assert counts == {k: v[0] for k, v in rep.timings.items()}
+
+
+def test_alpha_place_search_has_its_span(log_path):
+    ds = repro.open(log_path)
+    rep = ds.collect("alpha", engine="streaming").report
+    assert rep.timings["alpha.places"][0] == 1
+    assert rep.timings["alpha.places"][1] <= rep.timings["fold.finalize"][1]
+    assert "alpha.places" not in ds.collect("dfg",
+                                            engine="streaming").report.timings
